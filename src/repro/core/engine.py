@@ -44,6 +44,10 @@ __all__ = ["BACKENDS", "StencilEngine", "apply_stencil", "apply_1d"]
 
 ApplyFn = Callable[[jnp.ndarray], jnp.ndarray]
 
+#: f32 operands contract in f32: TPU's default f32 dot is a single bf16
+#: pass, which would drift from the f32 ``direct`` oracle.
+_F32_DOT = jax.lax.Precision.HIGHEST
+
 
 # ---------------------------------------------------------------------------
 # 1-D application primitives (stencil axis leading, free axis trailing).
@@ -94,7 +98,8 @@ def _op_gemm(K: np.ndarray, x2d: jnp.ndarray, n_out: int,
     Km = jnp.asarray(K, dtype=x2d.dtype)
     win, ntiles = _windows(x2d, n_out, L)
     y = jnp.einsum("lk,tkc->tlc", Km, win,
-                   preferred_element_type=jnp.float32).astype(x2d.dtype)
+                   preferred_element_type=jnp.float32,
+                   precision=_F32_DOT).astype(x2d.dtype)
     return y.reshape(ntiles * L, -1)[:n_out]
 
 
@@ -116,7 +121,8 @@ def _op_sptc(values: np.ndarray, comb: np.ndarray, x2d: jnp.ndarray,
     xg = x2d[jnp.asarray(rows)]                                 # (T, L, K/2, C)
     vals = jnp.asarray(values, dtype=x2d.dtype)
     y = jnp.einsum("mk,tmkc->tmc", vals, xg,
-                   preferred_element_type=jnp.float32).astype(x2d.dtype)
+                   preferred_element_type=jnp.float32,
+                   precision=_F32_DOT).astype(x2d.dtype)
     return y.reshape(ntiles * L, -1)[:n_out]
 
 
@@ -181,7 +187,8 @@ def _op_var_gemm(w2d: np.ndarray, gather: SegmentGatherSchedule, operand: int,
     win, ntiles = _windows(x2d, n_out, L)
     V = _values_tensor(w2d, gather.taps[operand], ntiles, L, n_out)
     y = jnp.einsum("tlsc,tsc->tlc", jnp.asarray(V, dtype=x2d.dtype), win,
-                   preferred_element_type=jnp.float32).astype(x2d.dtype)
+                   preferred_element_type=jnp.float32,
+                   precision=_F32_DOT).astype(x2d.dtype)
     return y.reshape(ntiles * L, -1)[:n_out]
 
 
@@ -193,7 +200,8 @@ def _op_var_sptc(w2d: np.ndarray, gather: SegmentGatherSchedule, operand: int,
     xg = x2d[jnp.asarray(rows)]                                 # (T, L, K/2, C)
     V = _values_tensor(w2d, gather.taps[operand], ntiles, L, n_out)
     y = jnp.einsum("tmsc,tmsc->tmc", jnp.asarray(V, dtype=x2d.dtype), xg,
-                   preferred_element_type=jnp.float32).astype(x2d.dtype)
+                   preferred_element_type=jnp.float32,
+                   precision=_F32_DOT).astype(x2d.dtype)
     return y.reshape(ntiles * L, -1)[:n_out]
 
 
@@ -310,7 +318,8 @@ def _emit_fused_2d(plan: LoweredPlan) -> ApplyFn:
         win, ntiles = _windows(xt, w_out, L, order=order)  # (T, 2L, H+2r)
         Km = jnp.asarray(K_all, dtype=x.dtype)
         y = jnp.einsum("lk,tkc->tlc", Km, win,
-                       preferred_element_type=jnp.float32
+                       preferred_element_type=jnp.float32,
+                       precision=_F32_DOT
                        ).astype(x.dtype)           # (T, R*L, H+2r)
         y = y.reshape(ntiles, R, L, h_in)
         yr = y.transpose(1, 0, 2, 3).reshape(R, ntiles * L, h_in)

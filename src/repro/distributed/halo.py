@@ -53,7 +53,6 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.engine import emit
@@ -297,8 +296,10 @@ class ShardedStencilEngine:
                 out, _ = jax.lax.scan(
                     lambda c, _: (local(c), None), blk, None, length=nblocks)
                 return out
-        y = shard_map(body, mesh=self.mesh,
-                      in_specs=self._pspec, out_specs=self._pspec)(up)
+        # Pallas kernels declare no per-axis variance on their outputs,
+        # so the variance check is off; the specs state the partition.
+        y = jax.shard_map(body, mesh=self.mesh, in_specs=self._pspec,
+                          out_specs=self._pspec, check_vma=False)(up)
         if padded:
             y = y[tuple(slice(0, s) for s in gshape)]
         return y

@@ -39,9 +39,15 @@ def backend_universe(device: str | None = None) -> str:
 
 def applicable_backends(spec: StencilSpec,
                         device: str | None = None) -> Tuple[str, ...]:
-    """Backends able to execute ``spec`` on ``device`` (default: current)."""
+    """Backends able to execute ``spec`` on ``device`` (default: current).
+
+    1-D specs get no Pallas backend: a 1-D grid reaches the kernels as an
+    (N, 1) column whose lane axis pads from 1 to 128, a 128x inflation
+    that runs out of HBM at paper size.  An engine built explicitly with
+    ``backend="pallas_*"`` still runs a 1-D spec.
+    """
     out = list(JNP_BACKENDS)
-    if backend_universe(device) == "jnp+pallas":
+    if backend_universe(device) == "jnp+pallas" and spec.ndim > 1:
         out.extend(PALLAS_BACKENDS)
     return tuple(out)
 

@@ -13,9 +13,9 @@ tiny K dim) followed by a dense MXU matmul over the N (free) dimension.
 runtime overhead"): ONE Pallas program that, per (N-tile, row-tile) grid
 step,
 
-  1. DMAs the overlapping (2L, bn) input window straight from HBM into
-     VMEM scratch, double-buffered across sequential grid steps (the
-     t+1 window prefetches while tile t computes);
+  1. DMAs the overlapping input window of its ``B`` output rows straight
+     from HBM into VMEM scratch, double-buffered across sequential grid
+     steps (the t+1 window prefetches while tile t computes);
   2. folds the strided row swap AND the 2-bit metadata gather into the
      decompression's comparison positions — the swap permutation is the
      closed form ``p odd: p <-> p±L`` so it is derived from an iota
@@ -33,8 +33,12 @@ over the banded value layout, touching no metadata at all.
 
 Blocking: the compressed operand (M = L, K/2) and metadata words are tiny
 and live whole in VMEM; the input stays in HBM (``pl.ANY``) because the
-overlapping 2L-row windows cannot be expressed as disjoint BlockSpec
-tiles; outputs are tiled (L, bn) with N in 128-lane multiples.
+overlapping windows cannot be expressed as disjoint BlockSpec tiles.  To
+fit v5e's (8, 128) f32 tile, one grid step stacks ``B // L`` row tiles of
+``L`` (``B`` = the least multiple of ``L`` filling whole sublane tiles: 8
+rows for L = 4, 24 for L = 6) and DMAs ``round_up(B + L, 8)`` window rows
+at row ``t·B``; outputs are tiled (B, bn) with N in 128-lane multiples.
+A leading batch grid axis serves ``vmap`` (``common.fold_vmap``).
 
 Both ``*_call`` entry points resolve ``interpret=None`` through
 ``common.default_interpret()`` at call time: compiled Mosaic on a real
@@ -43,6 +47,7 @@ TPU, interpret mode elsewhere, overridable via ``REPRO_PALLAS_INTERPRET``.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -66,7 +71,8 @@ def _sptc_kernel(values_ref, meta_ref, x_ref, y_ref, *, k: int):
     kpos = jax.lax.broadcasted_iota(jnp.int32, (m, kh, k), 2)
     onehot = (gidx[:, :, None] == kpos).astype(vals.dtype)
     w = jnp.sum(vals[:, :, None] * onehot, axis=1)          # (M, K)
-    y_ref[:] = jnp.dot(w, x, preferred_element_type=jnp.float32
+    y_ref[:] = jnp.dot(w, x, precision=common.dot_precision(x.dtype),
+                       preferred_element_type=jnp.float32
                        ).astype(y_ref.dtype)
 
 
@@ -110,14 +116,26 @@ def sptc_spmm_call(values, meta, x, *, block_n: int = 512,
 # ---------------------------------------------------------------------------
 
 def _fused_kernel(x_hbm, vals_ref, meta_ref, y_ref, scratch, sem, *,
-                  tiles: int, L: int, bn: int, star_fast: bool, compute):
-    t = pl.program_id(1)
-    j = pl.program_id(0)
-    kh = vals_ref.shape[1]
+                  tiles: int, L: int, rows: int, bn: int, star_fast: bool,
+                  compute):
+    """One grid step computes ``B`` output rows — ``B // L`` stacked row
+    tiles of ``L`` — of batch item ``b`` from a ``rows``-row window
+    starting at row ``t·B``.
+
+    ``B`` and ``rows`` are multiples of the sublane tile, so the output
+    block, the window DMA and its row offset are all (8, 128)-aligned;
+    ``vals_ref`` / ``meta_ref`` hold the operand's L rows repeated per
+    stacked tile (``B`` rows).
+    """
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    t = pl.program_id(2)
+    B, kh = vals_ref.shape
 
     def dma(slot, tt):
         return pltpu.make_async_copy(
-            x_hbm.at[pl.ds(tt * L, 2 * L), pl.ds(j * bn, bn)],
+            x_hbm.at[b, pl.ds(pl.multiple_of(tt * B, B), rows),
+                     pl.ds(pl.multiple_of(j * bn, bn), bn)],
             scratch.at[slot], sem.at[slot])
 
     # cross-grid-step double buffering: scratch persists across the
@@ -132,80 +150,98 @@ def _fused_kernel(x_hbm, vals_ref, meta_ref, y_ref, scratch, sem, *,
         dma((t + 1) % 2, t + 1).start()
 
     dma(t % 2, t).wait()
-    win = scratch[t % 2]                     # (2L, bn)
-    vals = vals_ref[:]                       # (M, K/2)
+    win = scratch[t % 2]                     # (rows, bn)
+    vals = vals_ref[:]                       # (B, K/2)
     if compute is not None:
         win = win.astype(compute)
         vals = vals.astype(compute)
+    vals = vals.astype(jnp.float32)
     if star_fast:
-        # banded value layout: row m's slot off reads window row m + off —
+        # banded value layout: row i's slot off reads window row i + off —
         # no metadata, K/2 shifted VPU FMAs with f32 accumulation.
-        acc = jnp.zeros((L, bn), dtype=jnp.float32)
+        acc = jnp.zeros((B, bn), dtype=jnp.float32)
         for jj in range(kh):
-            acc = acc + vals[:, jj:jj + 1].astype(jnp.float32) * \
-                win[jj:jj + L, :].astype(jnp.float32)
+            acc = acc + vals[:, jj:jj + 1] * \
+                win[jj:jj + B, :].astype(jnp.float32)
         y_ref[:] = acc.astype(y_ref.dtype)
-    else:
-        # unpack the 2-bit metadata from the packed words in-register
-        words = meta_ref[:]                  # (M, nwords) uint32
-        m = words.shape[0]
-        nwords = words.shape[1]
-        exp = jnp.concatenate(
-            [jnp.broadcast_to(words[:, w:w + 1], (m, 16))
-             for w in range(nwords)], axis=1)[:, :kh]
-        jj = jax.lax.broadcasted_iota(jnp.int32, (m, kh), 1)
-        shifts = (2 * (jj % 16)).astype(jnp.uint32)
-        meta = (jax.lax.shift_right_logical(exp, shifts) & 3
-                ).astype(jnp.int32)
-        gidx = 4 * (jj // 2) + meta                            # (M, K/2)
-        # strided swap folded into the decompression positions: position p
-        # of the window holds source row perm[p], and the permutation has
-        # the closed form "odd p exchanges halves" — derived from an iota,
-        # so the swap costs zero loads and zero stores (§3.3).
-        p = jax.lax.broadcasted_iota(jnp.int32, (m, kh, 2 * L), 2)
-        kpos = jnp.where(p % 2 == 1, jnp.where(p < L, p + L, p - L), p)
-        onehot = (gidx[:, :, None] == kpos)
-        w_dense = jnp.sum(vals[:, :, None] * onehot.astype(vals.dtype),
-                          axis=1)                              # (M, 2L)
-        y_ref[:] = jnp.dot(w_dense, win, preferred_element_type=jnp.float32
-                           ).astype(y_ref.dtype)
+        return
+    # unpack the 2-bit metadata from the packed words in-register
+    words = meta_ref[:]                      # (B, nwords) uint32
+    jj = jax.lax.broadcasted_iota(jnp.int32, (B, kh), 1)
+    exp = jnp.zeros((B, kh), jnp.uint32)
+    for w in range(words.shape[1]):
+        exp = jnp.where(jj // 16 == w, words[:, w:w + 1], exp)
+    meta = (jax.lax.shift_right_logical(exp, (2 * (jj % 16)).astype(
+        jnp.uint32)) & 3).astype(jnp.int32)
+    gidx = 4 * (jj // 2) + meta              # swapped-window position
+    # strided swap folded into the decompression positions: the swap
+    # "odd p exchanges halves" is an involution, so swapped position g
+    # reads window row kpos(g) — derived from an iota, zero loads and
+    # zero stores (§3.3).  Stacked tile s reads its window from row s·L.
+    kpos = jnp.where(gidx % 2 == 1,
+                     jnp.where(gidx < L, gidx + L, gidx - L), gidx)
+    i = jax.lax.broadcasted_iota(jnp.int32, (B, kh), 0)
+    col = (i // L) * L + kpos                # (B, K/2) window column
+    q = jax.lax.broadcasted_iota(jnp.int32, (B, rows), 1)
+    w_dense = jnp.zeros((B, rows), jnp.float32)
+    for s in range(kh):                      # one-hot decompression
+        w_dense = w_dense + jnp.where(q == col[:, s:s + 1],
+                                      vals[:, s:s + 1], 0.0)
+    w_dense = w_dense.astype(win.dtype)
+    y_ref[:] = jnp.dot(w_dense, win, precision=common.dot_precision(win.dtype),
+                       preferred_element_type=jnp.float32
+                       ).astype(y_ref.dtype)
+
+
+def _fused_geometry(L: int, dtype) -> "tuple[int, int]":
+    """(output rows per grid step ``B``, window rows) for a fused call.
+
+    ``B`` is the least multiple of ``L`` that fills whole sublane tiles
+    (8 rows of f32, 16 of bf16); the window covers the ``B + L`` rows those
+    outputs read, rounded up to the sublane tile.
+    """
+    sub = 8 * 4 // jnp.dtype(dtype).itemsize
+    B = L * sub // math.gcd(L, sub)
+    return B, round_up(B + L, sub)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "n_out", "L", "block_n", "star_fast", "compute_dtype", "interpret"))
-def _sptc_fused_jit(values, meta_bits, x2d, *, n_out: int, L: int,
+def _sptc_fused_jit(x3d, values, meta_bits, *, n_out: int, L: int,
                     block_n: int, star_fast: bool, compute_dtype,
                     interpret: bool):
-    rows, c = x2d.shape
-    m, kh = values.shape
-    tiles = -(-n_out // L)
-    need = (tiles + 1) * L
-    if need > rows:
-        x2d = jnp.pad(x2d, ((0, need - rows), (0, 0)))
+    nb, rows_in, c = x3d.shape
+    B, rows = _fused_geometry(L, x3d.dtype)
+    tiles = -(-n_out // B)
+    need = (tiles - 1) * B + rows
     bn = min(block_n, round_up(c, 128))
     c_pad = round_up(c, bn)
-    if c_pad != c:
-        x2d = jnp.pad(x2d, ((0, 0), (0, c_pad - c)))
+    if need > rows_in or c_pad != c:
+        x3d = jnp.pad(x3d, ((0, 0), (0, max(0, need - rows_in)),
+                            (0, c_pad - c)))
+    reps = B // L
+    values = jnp.tile(values, (reps, 1))
+    meta_bits = jnp.tile(meta_bits, (reps, 1))
     compute = jnp.dtype(compute_dtype) if compute_dtype else None
-    kern = functools.partial(_fused_kernel, tiles=tiles, L=L, bn=bn,
-                             star_fast=star_fast, compute=compute)
+    kern = functools.partial(_fused_kernel, tiles=tiles, L=L, rows=rows,
+                             bn=bn, star_fast=star_fast, compute=compute)
     y = pl.pallas_call(
         kern,
-        grid=(c_pad // bn, tiles),
+        grid=(nb, c_pad // bn, tiles),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),             # input in HBM
-            pl.BlockSpec((m, kh), lambda j, t: (0, 0)),
-            pl.BlockSpec(meta_bits.shape, lambda j, t: (0, 0)),
+            pl.BlockSpec(values.shape, lambda b, j, t: (0, 0)),
+            pl.BlockSpec(meta_bits.shape, lambda b, j, t: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((L, bn), lambda j, t: (t, j)),
-        out_shape=jax.ShapeDtypeStruct((tiles * L, c_pad), x2d.dtype),
+        out_specs=pl.BlockSpec((None, B, bn), lambda b, j, t: (b, t, j)),
+        out_shape=jax.ShapeDtypeStruct((nb, tiles * B, c_pad), x3d.dtype),
         scratch_shapes=[
-            pltpu.VMEM((2, 2 * L, bn), x2d.dtype),
+            pltpu.VMEM((2, rows, bn), x3d.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
-    )(x2d, values, meta_bits)
-    return y[:n_out, :c]
+    )(x3d, values, meta_bits)
+    return y[:, :n_out, :c]
 
 
 def sptc_fused_call(values, meta_bits, x2d, *, n_out: int, L: int,
@@ -219,10 +255,12 @@ def sptc_fused_call(values, meta_bits, x2d, *, n_out: int, L: int,
     ``meta_bits`` (L, ceil(K/32)) packed uint32 metadata words.
     ``x2d``       (>= n_out + L, C) input rows, UNswapped — the swap
                   happens inside the kernel.
-    Returns the (n_out, C) stencil output.
+    Returns the (n_out, C) stencil output.  ``vmap`` over ``x2d`` runs as
+    the kernel's own batch grid axis (``common.fold_vmap``).
     """
     if interpret is None:
         interpret = common.default_interpret()
-    return _sptc_fused_jit(values, meta_bits, x2d, n_out=n_out, L=L,
-                           block_n=block_n, star_fast=star_fast,
-                           compute_dtype=compute_dtype, interpret=interpret)
+    call = functools.partial(_sptc_fused_jit, n_out=n_out, L=L,
+                             block_n=block_n, star_fast=star_fast,
+                             compute_dtype=compute_dtype, interpret=interpret)
+    return common.fold_vmap(call)(x2d, values, meta_bits)

@@ -1,13 +1,17 @@
 """Pallas TPU kernel: tiled direct (VPU) 2-D stencil with DMA halo loads.
 
 This is the TPU bandwidth-roofline kernel. The input stays in HBM
-(``pl.ANY``); each grid step DMAs one (th + 2rh, W + 2rw) halo row-block
-into a VMEM scratch buffer — the overlapping halo rows are re-read from HBM
-exactly as a GPU kernel re-reads them into shared memory — then the output
-tile is accumulated with statically-unrolled shifted FMAs (one VPU
-multiply-add per non-zero tap; star stencils skip their zero taps at trace
-time). The stencil weights are compile-time constants, matching the paper's
-observation that the kernel matrix is static structure, not data.
+(``pl.ANY``); each grid step DMAs one (th + 2rh, tw + 2rw) halo block,
+rounded up to whole (8, 128) tiles, into a VMEM scratch buffer — the
+overlapping halo rows are re-read from HBM exactly as a GPU kernel re-reads
+them into shared memory — then the output tile is accumulated with
+statically-unrolled shifted FMAs (one VPU multiply-add per non-zero tap;
+star stencils skip their zero taps at trace time; a column offset is a lane
+rotate, not an unaligned slice).  Tiling W keeps scratch plus the
+double-buffered output block far below v5e's 16 MiB default scoped VMEM at
+any grid width.  The stencil weights are compile-time constants, matching
+the paper's observation that the kernel matrix is static structure, not
+data.
 
 Roofline: for an H x W fp32 grid the kernel moves ~4(H W) bytes in + 4(H W)
 out (+ halo), and performs taps x H x W FMAs — memory-bound for r <= 2,
@@ -25,54 +29,75 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import common
 
 
-def _stencil_kernel(x_hbm, y_ref, scratch, sem, *, taps, th, w_out, rh, rw):
-    i = pl.program_id(0)
-    rows = th + 2 * rh
+def _stencil_kernel(x_hbm, y_ref, scratch, sem, *, taps, th, tw, rows,
+                    cols):
+    b = pl.program_id(0)
+    i = pl.program_id(1)
+    j = pl.program_id(2)
     cp = pltpu.make_async_copy(
-        x_hbm.at[pl.ds(i * th, rows), :], scratch, sem)
+        x_hbm.at[b, pl.ds(pl.multiple_of(i * th, th), rows),
+                 pl.ds(pl.multiple_of(j * tw, tw), cols)],
+        scratch, sem)
     cp.start()
     cp.wait()
-    acc = jnp.zeros((th, w_out), dtype=jnp.float32)
-    for (u, v, wt) in taps:                     # statically unrolled VPU FMAs
-        acc = acc + wt * scratch[u:u + th, v:v + w_out].astype(jnp.float32)
+    acc = jnp.zeros((th, tw), dtype=jnp.float32)
+    for u in sorted({u for u, _, _ in taps}):   # statically unrolled VPU FMAs
+        band = scratch[u:u + th, :].astype(jnp.float32)      # (th, cols)
+        for (uu, v, wt) in taps:
+            if uu != u:
+                continue
+            # lane shift by v as an XLU rotate, then an aligned slice
+            shifted = pltpu.roll(band, cols - v, 1) if v else band
+            acc = acc + wt * shifted[:, :tw]
     y_ref[:] = acc.astype(y_ref.dtype)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("taps", "rh", "rw", "th", "interpret"))
-def _stencil2d_jit(x, *, taps, rh: int, rw: int, th: int, interpret: bool):
-    h_in, w_in = x.shape
+                   static_argnames=("taps", "rh", "rw", "th", "tw",
+                                    "interpret"))
+def _stencil2d_jit(x, *, taps, rh: int, rw: int, th: int, tw: int,
+                   interpret: bool):
+    nb, h_in, w_in = x.shape
     h_out = h_in - 2 * rh
     w_out = w_in - 2 * rw
     grid_h = -(-h_out // th)
-    # pad rows so the final tile's halo DMA stays in bounds
-    h_need = grid_h * th + 2 * rh
-    if h_need > h_in:
-        x = jnp.pad(x, ((0, h_need - h_in), (0, 0)))
+    grid_w = -(-w_out // tw)
+    # the halo DMA moves whole (8, 128) tiles: round its extent up and pad
+    # the input so the final tile's window stays in bounds
+    rows = common.round_up(th + 2 * rh, common.SUBLANES)
+    cols = common.round_up(tw + 2 * rw, common.LANES)
+    h_need = (grid_h - 1) * th + rows
+    w_need = (grid_w - 1) * tw + cols
+    if h_need > h_in or w_need > w_in:
+        x = jnp.pad(x, ((0, 0), (0, max(0, h_need - h_in)),
+                        (0, max(0, w_need - w_in))))
     y = pl.pallas_call(
-        functools.partial(_stencil_kernel, taps=taps, th=th,
-                          w_out=w_out, rh=rh, rw=rw),
-        grid=(grid_h,),
+        functools.partial(_stencil_kernel, taps=taps, th=th, tw=tw,
+                          rows=rows, cols=cols),
+        grid=(nb, grid_h, grid_w),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((th, w_out), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((grid_h * th, w_out), x.dtype),
+        out_specs=pl.BlockSpec((None, th, tw), lambda b, i, j: (b, i, j)),
+        out_shape=jax.ShapeDtypeStruct((nb, grid_h * th, grid_w * tw),
+                                       x.dtype),
         scratch_shapes=[
-            pltpu.VMEM((th + 2 * rh, w_in), x.dtype),
+            pltpu.VMEM((rows, cols), x.dtype),
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,
     )(x)
-    return y[:h_out]
+    return y[:, :h_out, :w_out]
 
 
 def stencil2d_call(x, *, taps, rh: int, rw: int, th: int = 128,
-                   interpret: bool | None = None):
+                   tw: int = 512, interpret: bool | None = None):
     """Apply a 2-D stencil. x: (H + 2rh, W + 2rw) -> (H, W).
 
     ``taps`` is a static tuple of (u, v, weight) non-zero stencil entries.
-    Caller is responsible for lane padding of W (ops.py handles it).
+    Output tiles are (th, tw): ``th`` a multiple of 8, ``tw`` of 128.
+    ``vmap`` over ``x`` runs as the kernel's own batch grid axis.
     """
     if interpret is None:
         interpret = common.default_interpret()
-    return _stencil2d_jit(x, taps=taps, rh=rh, rw=rw, th=th,
-                          interpret=interpret)
+    call = functools.partial(_stencil2d_jit, taps=taps, rh=rh, rw=rw, th=th,
+                             tw=tw, interpret=interpret)
+    return common.fold_vmap(call)(x)

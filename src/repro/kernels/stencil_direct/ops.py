@@ -25,15 +25,10 @@ def stencil2d(weights: np.ndarray, x, *, th: int = 128,
     kh, kw = weights.shape
     rh, rw = (kh - 1) // 2, (kw - 1) // 2
     h_in, w_in = x.shape
-    w_out = w_in - 2 * rw
-    # lane padding: output width to 128 multiple (zero-pad input columns)
-    w_out_p = common.round_up(max(w_out, 1), common.LANES)
-    if w_out_p != w_out:
-        x = jnp.pad(x, ((0, 0), (0, w_out_p - w_out)))
     th = min(th, common.round_up(h_in - 2 * rh, common.SUBLANES))
-    y = stencil2d_call(x, taps=_taps(weights), rh=rh, rw=rw, th=th,
-                       interpret=interpret)
-    return y[:, :w_out]
+    tw = min(512, common.round_up(w_in - 2 * rw, common.LANES))
+    return stencil2d_call(jnp.asarray(x), taps=_taps(weights), rh=rh, rw=rw,
+                          th=th, tw=tw, interpret=interpret)
 
 
 def stencil1d(weights: np.ndarray, x, *, interpret: bool | None = None):

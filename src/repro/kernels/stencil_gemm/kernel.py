@@ -24,7 +24,8 @@ from repro.kernels.common import round_up
 def _gemm_kernel(km_ref, win_ref, y_ref):
     km = km_ref[:]                    # (L, K)
     win = win_ref[0]                  # (K, bn)
-    y_ref[0] = jnp.dot(km, win, preferred_element_type=jnp.float32
+    y_ref[0] = jnp.dot(km, win, precision=common.dot_precision(win.dtype),
+                       preferred_element_type=jnp.float32
                        ).astype(y_ref.dtype)
 
 

@@ -26,7 +26,7 @@ from repro.configs.registry import (ARCHS, get_config,           # noqa: E402
 from repro.distributed.sharding import (default_rules,           # noqa: E402
                                         param_shardings, spec_for,
                                         use_mesh_rules)
-from repro.launch.mesh import make_production_mesh               # noqa: E402
+from repro.launch.mesh import make_auto_mesh, make_production_mesh  # noqa: E402
 from repro.models import model as M                              # noqa: E402
 from repro.models.nn import axes_tree                            # noqa: E402
 from repro.roofline.analysis import (from_compiled,              # noqa: E402
@@ -121,7 +121,7 @@ def lower_cell(arch: str, cell: ShapeCell, *, multi_pod: bool,
     cfg = cfg_override or get_config(arch)
     if mesh_override is not None:
         shape, axes = mesh_override
-        mesh = jax.make_mesh(shape, axes)
+        mesh = make_auto_mesh(shape, axes)
         mesh_name = "x".join(map(str, shape)) + extra_tag
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)

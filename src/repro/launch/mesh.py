@@ -17,10 +17,18 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever this process actually has — smoke/bench mesh."""
     n = jax.device_count()
-    return jax.make_mesh((n,), ("data",))
+    return make_auto_mesh((n,), ("data",))
+
+
+def make_auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: sharding follows the
+    ``with_sharding_constraint`` hints, as the training step expects
+    (``make_mesh`` itself defaults to ``Explicit`` axes)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

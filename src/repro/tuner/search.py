@@ -119,8 +119,7 @@ def static_cost(spec: StencilSpec, plan: Plan) -> float:
 @dataclasses.dataclass(frozen=True)
 class Candidate:
     plan: Plan
-    score: float | None        # seconds (time mode) or model cost (cost mode)
-    error: str | None = None
+    score: float               # seconds (time mode) or model cost (cost mode)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -131,8 +130,7 @@ class TuneResult:
 
     @property
     def best_score(self) -> float:
-        return min(c.score for c in self.candidates
-                   if c.error is None and c.plan == self.plan)
+        return min(c.score for c in self.candidates if c.plan == self.plan)
 
 
 def _default_engine_factory(spec: StencilSpec, plan: Plan,
@@ -169,9 +167,10 @@ def autotune(spec: StencilSpec, shape: Sequence[int],
     ``shape`` is the halo-inclusive input shape, exactly what the engine
     will be called with (for a k-step temporal block that means the k·r
     halo; for variable coefficients it must match the field's fixed
-    shape).  Candidates that fail to build or run are skipped (recorded
-    with their error).  If every timed candidate fails — or ``mode ==
-    "cost"`` — selection falls back to the static cost model.
+    shape).  ``mode == "cost"`` ranks by the static cost model.  In
+    ``"time"`` mode a candidate that fails to build, compile or run raises
+    ``RuntimeError`` naming its plan: every applicable backend must work
+    on this device, so a failure is a fault, not a slower plan.
     """
     if mode not in ("time", "cost"):
         raise ValueError(f"mode must be 'time' or 'cost', got {mode!r}")
@@ -193,15 +192,10 @@ def autotune(spec: StencilSpec, shape: Sequence[int],
         try:
             eng = factory(spec, p, coefficients=coefficients)
             t = measure(eng, x, warmup=warmup, iters=iters)
-            cands.append(Candidate(p, t))
-        except Exception as e:  # noqa: BLE001 — any backend failure skips it
-            cands.append(Candidate(p, None, error=f"{type(e).__name__}: {e}"))
-    timed = [c for c in cands if c.error is None]
-    if not timed:
-        fallback = autotune(spec, shape, dtype, mode="cost",
-                            temporal_steps=temporal_steps,
-                            coefficients=coefficients)
-        return TuneResult(plan=fallback.plan, mode="cost",
-                          candidates=tuple(cands) + fallback.candidates)
-    best = min(timed, key=lambda c: c.score)
+        except Exception as e:
+            raise RuntimeError(
+                f"autotune candidate {p} for {spec.name} on input "
+                f"{tuple(shape)} failed to build, compile or run") from e
+        cands.append(Candidate(p, t))
+    best = min(cands, key=lambda c: c.score)
     return TuneResult(plan=best.plan, mode="time", candidates=tuple(cands))

@@ -295,7 +295,8 @@ _SHARDED_PATH = "src/repro/distributed/halo.py"
 _GATHER_LIKE = ("all-gather", "all-to-all", "all-reduce", "reduce-scatter")
 
 
-def _collective_counts(text: str) -> Dict[str, int]:
+def collective_counts(text: str) -> Dict[str, int]:
+    """Collective-permutes and gather-shaped collectives in compiled HLO."""
     hist = hlo_parse.opcode_histogram(hlo_parse.parse_module(text))
     permutes = (hist.get("collective-permute", 0)
                 + hist.get("collective-permute-start", 0))
@@ -351,7 +352,7 @@ def analyze_sharded(cfg: VetConfig
         for tag, nblocks in (("step", 1), ("iterate", 2)):
             text = jax.jit(engine._run_sharded, static_argnums=1).lower(
                 u, nblocks).compile().as_text()
-            counts = _collective_counts(text)
+            counts = collective_counts(text)
             per_probe[f"{symbol}/{tag}"] = counts
             expected = 2 * naxes
             if counts["collective-permute"] != expected:

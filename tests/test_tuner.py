@@ -6,6 +6,7 @@ must round-trip through the JSON file; and every tuned plan must stay
 numerically equal to the `direct` backend oracle across paper_suite().
 """
 import json
+import re
 
 import jax.numpy as jnp
 import numpy as np
@@ -75,6 +76,18 @@ def test_candidates_are_applicable_and_valid():
             assert static_cost(spec, p) > 0
 
 
+def test_tpu_candidates_hold_pallas_only_beyond_1d(monkeypatch):
+    """On a TPU every 2-D spec is offered all three Pallas backends; a 1-D
+    spec none (its (N, 1) column would pad 128x on the lane axis)."""
+    from repro.kernels.dispatch import PALLAS_BACKENDS
+    monkeypatch.delenv("REPRO_TUNER_INCLUDE_PALLAS", raising=False)
+    for spec in paper_suite():
+        got = applicable_backends(spec, "tpu")
+        pallas = [b for b in got if b in PALLAS_BACKENDS]
+        assert pallas == ([] if spec.ndim == 1 else list(PALLAS_BACKENDS))
+        assert set(got) - set(pallas) == {"direct", "gemm", "sptc"}
+
+
 def test_cost_mode_autotune_builds_nothing():
     spec = make_stencil("box", 2, 3, seed=0)
     calls = []
@@ -106,8 +119,7 @@ def test_repeat_apply_hits_cache_no_rejit(rng):
     # the jitted executable was not re-traced either
     plan = plan_for(spec, x.shape, x.dtype, cache=cache, mode="cost")
     eng = cache.engine(spec, plan)
-    if hasattr(eng._fn, "_cache_size"):
-        assert eng._fn._cache_size() == 1
+    assert eng._fn._cache_size() == 1
 
 
 def test_apply_stencil_reuses_engine_across_calls(rng):
@@ -494,7 +506,26 @@ def test_timing_mode_smoke(rng):
     res = autotune(spec, x.shape, x.dtype, mode="time", warmup=1, iters=2)
     assert res.mode == "time"
     assert res.plan in candidate_plans(spec)
-    assert any(c.error is None and c.score > 0 for c in res.candidates)
+    assert all(c.score > 0 for c in res.candidates)
+
+
+def test_timing_mode_raises_naming_the_failing_plan(rng):
+    """A candidate that fails to build/compile/run is a fault on this
+    device: autotune raises and names the plan, never skips it."""
+    from repro.tuner.search import _default_engine_factory
+    spec = make_stencil("box", 1, 1, seed=12)
+    x = _x(spec, (64,), rng)
+    bad = next(p for p in candidate_plans(spec) if p.backend == "gemm")
+
+    def factory(s, p, coefficients=None):
+        if p == bad:
+            raise ValueError("kernel refused")
+        return _default_engine_factory(s, p, coefficients=coefficients)
+
+    with pytest.raises(RuntimeError, match=re.escape(str(bad))) as ei:
+        autotune(spec, x.shape, x.dtype, mode="time", iters=1,
+                 engine_factory=factory)
+    assert isinstance(ei.value.__cause__, ValueError)
 
 
 def test_time_mode_prunes_losing_candidate_engines(rng):
