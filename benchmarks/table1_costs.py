@@ -46,8 +46,6 @@ def main(csv: bool = True):
 
 
 if __name__ == "__main__":
-    from pathlib import Path
-
     from repro.compile_cache import use_checkout_cache
-    use_checkout_cache(Path(__file__).resolve().parents[1])
+    use_checkout_cache()
     main()

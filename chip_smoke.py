@@ -229,7 +229,7 @@ def main(argv=None) -> int:
         print(f"chip_smoke: --chips {args.chips} but only {len(devices)} "
               "devices", file=sys.stderr)
         return 2
-    cache_dir = use_checkout_cache(ROOT)
+    cache_dir = use_checkout_cache()
     compiles = _Compiles()
     jax.monitoring.register_event_listener(compiles.on_event)
     jax.monitoring.register_event_duration_secs_listener(

@@ -13,9 +13,11 @@ from pathlib import Path
 import jax
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+#: ``<checkout>/.jax_cache``: this file is ``<checkout>/src/repro/...``.
+CHECKOUT_CACHE = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
-def use_checkout_cache(checkout: str | os.PathLike) -> str:
+def use_checkout_cache() -> str:
     """Keep compiled programs under ``<checkout>/.jax_cache``; return the dir.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
@@ -26,6 +28,5 @@ def use_checkout_cache(checkout: str | os.PathLike) -> str:
     env = os.environ.get(ENV_VAR)
     if env:
         return env
-    path = str(Path(checkout).resolve() / ".jax_cache")
-    jax.config.update("jax_compilation_cache_dir", path)
-    return path
+    jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_CACHE))
+    return str(CHECKOUT_CACHE)
