@@ -41,9 +41,9 @@ def one_chip(topo):
 
 @pytest.fixture
 def tpu_compile(monkeypatch):
-    """Compiled Mosaic (default_interpret() sees the CPU here), and no
-    persistent cache: an entry compiled for a described chip cannot be
-    read back without one."""
+    """Compiled Mosaic (default_interpret() sees the CPU here), no
+    persistent cache (an entry compiled for a described chip cannot be
+    read back without one) and no TPU runtime log files."""
     from jax.experimental.compilation_cache import compilation_cache
     monkeypatch.setenv(INTERPRET_ENV_VAR, "0")
     monkeypatch.setenv("TPU_LOG_DIR", "disabled")
