@@ -32,7 +32,7 @@ from repro.core.sptc import sptc_matmul
 from repro.core.transform import kernel_matrix
 
 SCHEMA = "repro/bench_kernels"
-VERSION = 2
+VERSION = 3
 
 
 def bench(fn, *args, iters=20):
@@ -76,21 +76,15 @@ def spmm_sweep(radii, n, iters, seed=0):
 
 
 def fused_kernel_sweep(radii, n, seed=2):
-    """Fused pallas_sptc v2 vs the direct oracle, with roofline fractions.
+    """Fused pallas_sptc v2 vs the direct oracle.
 
     All three kernel paths (general one-hot, star-fast banded, bf16
     compute) run in interpret mode and are checked allclose against the
-    NumPy direct stencil.  The roofline columns model the TPU v5e target:
-    ``roofline_us`` is the two-term hardware-limit time for the fused
-    program's FLOPs/bytes; ``attained_frac_interp`` divides that by the
-    measured wall clock — on CPU interpret mode this is (intentionally)
-    tiny and recorded only for provenance, on a real TPU the same code
-    path reports the true attained fraction.
+    NumPy direct stencil.  Device time and roofline shares come from the
+    chip benchmark (``bench/``), not from this wall clock.
     """
     from repro.core.sparsify import sparsify_stencil_kernel
     from repro.kernels.sptc_spmm.ops import sptc_spmm_fused
-    from repro.roofline.analysis import (attained_fraction,
-                                         kernel_roofline_time)
     rng = np.random.default_rng(seed)
     rows = []
     for r in radii:
@@ -115,11 +109,6 @@ def fused_kernel_sweep(radii, n, seed=2):
         t_star, err_star = run_path("auto")
         _, err_bf16 = run_path("auto", "bfloat16")
         tol = 2e-4 * max(1.0, float(np.max(np.abs(want))))
-        # fused program work: K/2 = L MACs per output point (the 2:4
-        # compression halves the dense 2L), streamed input + output bytes
-        tiles = -(-n_out // L)
-        flops = 2.0 * n_out * n * L
-        hbm_bytes = 4.0 * n * ((tiles + 1) * L + n_out)
         rows.append({
             "radius": r, "L": L, "n_out": n_out, "n": n,
             "general_ok": bool(err_gen <= tol),
@@ -128,11 +117,7 @@ def fused_kernel_sweep(radii, n, seed=2):
                 1.0, float(np.max(np.abs(want))))),
             "max_err_f32": round(max(err_gen, err_star), 8),
             "max_err_bf16": round(err_bf16, 6),
-            "roofline_us": round(
-                kernel_roofline_time(flops, hbm_bytes) * 1e6, 4),
             "interp_cpu_us": round(t_star * 1e6, 1),
-            "attained_frac_interp": round(
-                attained_fraction(t_star, flops, hbm_bytes), 8),
         })
     return rows
 
@@ -253,13 +238,12 @@ def main(argv=None):
     print("# sptc executes K/2 — per-useful-MAC throughput is the metric")
     print()
     print("# fused pallas_sptc v2 (interpret mode) vs direct oracle")
-    print("radius,L,general_ok,star_fast_ok,bf16_ok,roofline_us,"
-          "interp_cpu_us")
+    print("radius,L,general_ok,star_fast_ok,bf16_ok,interp_cpu_us")
     for row in payload["fused_kernel"]:
         print(f"{row['radius']},{row['L']},{row['general_ok']},"
               f"{row['star_fast_ok']},{row['bf16_ok']},"
-              f"{row['roofline_us']},{row['interp_cpu_us']}")
-    print("# roofline_us models TPU v5e; interp wall clock is CPU Python")
+              f"{row['interp_cpu_us']}")
+    print("# interp wall clock is CPU Python")
     print()
     print("# fused engine (registry x radius): pallas_sptc vs direct")
     for row in payload["fused_engine"]:

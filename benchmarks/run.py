@@ -14,12 +14,10 @@ def _section(title):
 
 def main() -> None:
     t0 = time.time()
-    from benchmarks import (fig9_throughput, fig10_scaling, kernel_bench,
-                            roofline_table, serving_bench, table1_costs)
+    from benchmarks import (fig10_scaling, kernel_bench, roofline_table,
+                            serving_bench, table1_costs)
     _section("Table 1 — analytic cost model (paper §2.3/§3.2.3)")
     table1_costs.main()
-    _section("Figure 9 — throughput across stencil shapes")
-    fig9_throughput.main()
     _section("Figure 10 — throughput vs problem size")
     fig10_scaling.main([])
     _section("Kernel microbench — dense GEMM vs 2:4 SpMM")

@@ -56,7 +56,7 @@ from repro.tuner import PlanCache, plan_for  # noqa: E402
 from repro.vet.lowering import collective_counts  # noqa: E402
 
 SEED = 0
-SIDE = 10240            # the paper's 2-D size (benchmarks/fig9_throughput.py)
+SIDE = 10240            # the paper's 2-D size (bench/configs/*.json)
 STEPS = 4
 #: |result - direct| bound for float32 inputs drawn from N(0, 1).  Every
 #: paper-suite stencil is a convex smoothing kernel, so values stay O(1);

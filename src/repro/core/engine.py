@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import scopes
 from repro.core.ir import (BACKENDS, LoweredPlan, RowOp,
                            SegmentGatherSchedule)
 from repro.core.sparsify import Sparse24, decode_24, sparsify_stencil_kernel
@@ -70,10 +71,11 @@ def _windows(x2d: jnp.ndarray, n_out: int, L: int,
     """
     ntiles = -(-n_out // L)
     need = (ntiles + 1) * L
-    x2d = jnp.pad(x2d, ((0, max(0, need - x2d.shape[0])), (0, 0)))
     within = np.arange(2 * L) if order is None else np.asarray(order)
-    idx = (jnp.arange(ntiles) * L)[:, None] + jnp.asarray(within)[None, :]
-    return x2d[idx], ntiles
+    with jax.named_scope(scopes.KERNEL_PREP):
+        x2d = jnp.pad(x2d, ((0, max(0, need - x2d.shape[0])), (0, 0)))
+        idx = (jnp.arange(ntiles) * L)[:, None] + jnp.asarray(within)[None, :]
+        return x2d[idx], ntiles
 
 
 def _pad_tiles(x2d: jnp.ndarray, n_out: int, L: int
@@ -81,7 +83,9 @@ def _pad_tiles(x2d: jnp.ndarray, n_out: int, L: int
     """Zero-pad the row axis so ``ntiles`` full tile reads are in-bounds."""
     ntiles = -(-n_out // L)
     need = (ntiles + 1) * L
-    return jnp.pad(x2d, ((0, max(0, need - x2d.shape[0])), (0, 0))), ntiles
+    with jax.named_scope(scopes.KERNEL_PREP):
+        x2d = jnp.pad(x2d, ((0, max(0, need - x2d.shape[0])), (0, 0)))
+    return x2d, ntiles
 
 
 def _op_direct(w: np.ndarray, x2d: jnp.ndarray, n_out: int) -> jnp.ndarray:
@@ -209,9 +213,15 @@ def _op_var_sptc(w2d: np.ndarray, gather: SegmentGatherSchedule, operand: int,
 # The stage interpreter: LoweredPlan -> traced jnp program.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope(scopes.LAYOUT)
 def _apply_op(plan: LoweredPlan, op: RowOp, x: jnp.ndarray, n_out: int,
               axis: int) -> jnp.ndarray:
-    """Execute one constant-coefficient RowOp from the plan's tables."""
+    """Execute one constant-coefficient RowOp from the plan's tables.
+
+    All of it but the kernel wrappers' own copies counts as the axis move
+    around the kernel: the layout copies the compiler makes for a kernel's
+    fixed operand layout carry the kernel call's scope.
+    """
     x = jnp.moveaxis(x, axis, 0)
     rest = x.shape[1:]
     x2d = x.reshape(x.shape[0], -1)
@@ -271,6 +281,7 @@ def _emit_const(plan: LoweredPlan) -> ApplyFn:
             return _apply_op(plan, op0, x, n_out, 0)
         return fn1
 
+    @jax.named_scope(scopes.ACCUMULATE)
     def fn(x: jnp.ndarray) -> jnp.ndarray:
         out_shape = tuple(s - 2 * r for s in x.shape)
         acc = jnp.zeros(out_shape, dtype=x.dtype)
@@ -309,11 +320,13 @@ def _emit_fused_2d(plan: LoweredPlan) -> ApplyFn:
     K_all = np.concatenate(mats, axis=0)          # (R*L, 2L)
     leads = [int(op.lead[0]) for op in dec.ops]
 
+    @jax.named_scope(scopes.ACCUMULATE)
     def fn(x: jnp.ndarray) -> jnp.ndarray:
         h_in = x.shape[0]
         h_out = h_in - 2 * r
         w_out = x.shape[1] - 2 * r
-        xt = x.T                                   # (W+2r, H+2r)
+        with jax.named_scope(scopes.LAYOUT):
+            xt = x.T                               # (W+2r, H+2r)
         # zero-cost row swap: perm folds into the window gather (§3.3)
         win, ntiles = _windows(xt, w_out, L, order=order)  # (T, 2L, H+2r)
         Km = jnp.asarray(K_all, dtype=x.dtype)
@@ -321,12 +334,14 @@ def _emit_fused_2d(plan: LoweredPlan) -> ApplyFn:
                        preferred_element_type=jnp.float32,
                        precision=_F32_DOT
                        ).astype(x.dtype)           # (T, R*L, H+2r)
-        y = y.reshape(ntiles, R, L, h_in)
-        yr = y.transpose(1, 0, 2, 3).reshape(R, ntiles * L, h_in)
+        with jax.named_scope(scopes.LAYOUT):
+            y = y.reshape(ntiles, R, L, h_in)
+            yr = y.transpose(1, 0, 2, 3).reshape(R, ntiles * L, h_in)
         acc = jnp.zeros((w_out, h_out), dtype=x.dtype)
         for i, u in enumerate(leads):
             acc = acc + yr[i, :w_out, u:u + h_out]
-        return acc.T
+        with jax.named_scope(scopes.LAYOUT):
+            return acc.T
     return fn
 
 
@@ -358,6 +373,7 @@ def _emit_var(plan: LoweredPlan) -> ApplyFn:
         w2d = _var_slab_2d(dec.coefficients[op.operand], axis)
         per_op.append((op, sl, axis, w2d))
 
+    @jax.named_scope(scopes.ACCUMULATE)
     def fn(x: jnp.ndarray) -> jnp.ndarray:
         if tuple(x.shape) != in_shape:
             raise ValueError(
@@ -366,9 +382,11 @@ def _emit_var(plan: LoweredPlan) -> ApplyFn:
                 f"{tuple(x.shape)}")
         acc = jnp.zeros(out_shape, dtype=x.dtype)
         for op, sl, axis, w2d in per_op:
-            xs = jnp.moveaxis(x[sl], axis, 0)
-            rest = xs.shape[1:]
-            x2d = xs.reshape(xs.shape[0], -1)
+            xs = x[sl]
+            with jax.named_scope(scopes.LAYOUT):
+                xs = jnp.moveaxis(xs, axis, 0)
+                rest = xs.shape[1:]
+                x2d = xs.reshape(xs.shape[0], -1)
             n_out = out_shape[axis]
             if backend == "direct":
                 y2d = _op_var_direct(w2d, x2d, n_out)
@@ -381,7 +399,8 @@ def _emit_var(plan: LoweredPlan) -> ApplyFn:
             else:
                 raise ValueError(
                     f"variable coefficients unsupported on {backend}")
-            y = jnp.moveaxis(y2d.reshape((n_out,) + rest), 0, axis)
+            with jax.named_scope(scopes.LAYOUT):
+                y = jnp.moveaxis(y2d.reshape((n_out,) + rest), 0, axis)
             acc = acc + y
         return acc
     return fn
@@ -466,7 +485,8 @@ class StencilEngine:
 
         def body(x_in: jnp.ndarray, _: None) -> Tuple[jnp.ndarray, None]:
             y = self._fn(x_in)
-            return jnp.pad(y, pad), None
+            with jax.named_scope(scopes.REPAD):
+                return jnp.pad(y, pad), None
 
         out, _ = jax.lax.scan(body, x, None, length=steps // k)
         return out

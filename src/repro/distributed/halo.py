@@ -55,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro.core import scopes
 from repro.core.engine import emit
 from repro.core.ir import LoweredPlan
 from repro.core.stencil import StencilSpec
@@ -229,7 +230,8 @@ class ShardedStencilEngine:
         def fn(u: jnp.ndarray) -> jnp.ndarray:
             # unpartitioned axes take the physical zero boundary directly
             pads = [(0, 0) if a in part else (h, h) for a in range(d)]
-            base = jnp.pad(u, pads)
+            with jax.named_scope(scopes.HALO_EXCHANGE):
+                base = jnp.pad(u, pads)
             # issue every exchange first: 2 ppermutes per partitioned
             # axis.  Later axes send edges of the already-extended array
             # so corner halos arrive through two hops (box stencils read
@@ -241,10 +243,12 @@ class ShardedStencilEngine:
                 fwd = [(i, i + 1) for i in range(n - 1)]
                 bwd = [(i + 1, i) for i in range(n - 1)]
                 size = ext.shape[a]
-                lo = jax.lax.ppermute(_take(ext, a, size - h, size),
-                                      name, fwd)
-                hi = jax.lax.ppermute(_take(ext, a, 0, h), name, bwd)
-                ext = jnp.concatenate([lo, ext, hi], axis=a)
+                with jax.named_scope(scopes.HALO_EXCHANGE):
+                    lo = jax.lax.ppermute(_take(ext, a, size - h, size),
+                                          name, fwd)
+                    hi = jax.lax.ppermute(_take(ext, a, 0, h), name, bwd)
+                with jax.named_scope(scopes.HALO_STITCH):
+                    ext = jnp.concatenate([lo, ext, hi], axis=a)
             # interior: reads only the pre-exchange block, so it carries
             # no dependence on the collectives and overlaps the exchange
             y = step(base)
@@ -259,22 +263,24 @@ class ShardedStencilEngine:
                     sl_lo[a2] = sl_hi[a2] = slice(h, blocks[a2] + h)
                 sl_lo[a] = slice(0, 3 * h)
                 sl_hi[a] = slice(b - h, b + 2 * h)
-                y = jnp.concatenate(
-                    [step(ext[tuple(sl_lo)]), y, step(ext[tuple(sl_hi)])],
-                    axis=a)
+                with jax.named_scope(scopes.HALO_STITCH):
+                    y = jnp.concatenate(
+                        [step(ext[tuple(sl_lo)]), y, step(ext[tuple(sl_hi)])],
+                        axis=a)
             # zero the phantom rows of a non-divisible dim so iterated
             # steps keep reading zero-flux values past the true boundary
-            mask = None
-            for a in paxes:
-                name, n = part[a]
-                b = blocks[a]
-                if b * n != gshape[a]:
-                    gi = jax.lax.axis_index(name) * b + jnp.arange(b)
-                    m = (gi < gshape[a]).reshape(
-                        (1,) * a + (b,) + (1,) * (d - a - 1))
-                    mask = m if mask is None else mask & m
-            if mask is not None:
-                y = jnp.where(mask, y, jnp.zeros((), dtype=y.dtype))
+            with jax.named_scope(scopes.HALO_STITCH):
+                mask = None
+                for a in paxes:
+                    name, n = part[a]
+                    b = blocks[a]
+                    if b * n != gshape[a]:
+                        gi = jax.lax.axis_index(name) * b + jnp.arange(b)
+                        m = (gi < gshape[a]).reshape(
+                            (1,) * a + (b,) + (1,) * (d - a - 1))
+                        mask = m if mask is None else mask & m
+                if mask is not None:
+                    y = jnp.where(mask, y, jnp.zeros((), dtype=y.dtype))
             return y
 
         return fn

@@ -7,6 +7,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.core.scopes import KERNEL_PREP
+
 #: env override for interpret-mode resolution: "1" forces interpret=True
 #: everywhere (correctness sweeps on any backend), "0" forces compiled
 #: Mosaic lowering (only meaningful on a real TPU).
@@ -50,8 +52,11 @@ def fold_vmap(call: Callable) -> Callable:
     @jax.custom_batching.custom_vmap
     def f(x, *consts):
         lead = x.shape[:-2]
-        y = call(x.reshape((-1,) + x.shape[-2:]), *consts)
-        return y.reshape(lead + y.shape[-2:])
+        with jax.named_scope(KERNEL_PREP):
+            x = x.reshape((-1,) + x.shape[-2:])
+        y = call(x, *consts)
+        with jax.named_scope(KERNEL_PREP):
+            return y.reshape(lead + y.shape[-2:])
 
     @f.def_vmap
     def _rule(axis_size, in_batched, x, *consts):

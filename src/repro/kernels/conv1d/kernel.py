@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.scopes import KERNEL_PREP
 from repro.kernels import common
 
 
@@ -45,9 +46,12 @@ def _conv1d_jit(x, w, *, block_t: int, interpret: bool):
     bt = min(block_t, t)
     nt = -(-t // bt)
     # causal left halo + pad tail so every tile's DMA window is in bounds
-    x = jnp.pad(x, ((0, 0), (k - 1, nt * bt - t), (0, 0)))
+    with jax.named_scope(KERNEL_PREP):
+        x = jnp.pad(x, ((0, 0), (k - 1, nt * bt - t), (0, 0)))
+        w = w.astype(x.dtype)
     y = pl.pallas_call(
         functools.partial(_conv_kernel, k=k, bt=bt),
+        name="conv1d",
         grid=(bsz, nt),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
@@ -60,8 +64,9 @@ def _conv1d_jit(x, w, *, block_t: int, interpret: bool):
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,
-    )(x, w.astype(x.dtype))
-    return y[:, :t, :]
+    )(x, w)
+    with jax.named_scope(KERNEL_PREP):
+        return y[:, :t, :]
 
 
 def conv1d_causal_call(x, w, *, block_t: int = 256,

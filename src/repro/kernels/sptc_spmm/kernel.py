@@ -54,6 +54,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.scopes import KERNEL_PREP
 from repro.kernels import common
 from repro.kernels.common import round_up
 
@@ -84,11 +85,15 @@ def _sptc_spmm_jit(values, meta, x, *, block_n: int, interpret: bool):
         raise ValueError(f"K/2 mismatch: values {kh} vs x K={k}")
     bn = min(block_n, round_up(n, 128))
     n_pad = round_up(n, bn)
-    if n_pad != n:
-        x = jnp.pad(x, ((0, 0), (0, n_pad - n)))
+    with jax.named_scope(KERNEL_PREP):
+        if n_pad != n:
+            x = jnp.pad(x, ((0, 0), (0, n_pad - n)))
+        values = values.astype(x.dtype)
+        meta = meta.astype(jnp.int32)
     grid = (n_pad // bn,)
     y = pl.pallas_call(
         functools.partial(_sptc_kernel, k=k),
+        name="sptc_spmm",
         grid=grid,
         in_specs=[
             pl.BlockSpec((m, kh), lambda i: (0, 0)),     # compressed values
@@ -98,8 +103,9 @@ def _sptc_spmm_jit(values, meta, x, *, block_n: int, interpret: bool):
         out_specs=pl.BlockSpec((m, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((m, n_pad), x.dtype),
         interpret=interpret,
-    )(values.astype(x.dtype), meta.astype(jnp.int32), x)
-    return y[:, :n]
+    )(values, meta, x)
+    with jax.named_scope(KERNEL_PREP):
+        return y[:, :n]
 
 
 def sptc_spmm_call(values, meta, x, *, block_n: int = 512,
@@ -216,17 +222,19 @@ def _sptc_fused_jit(x3d, values, meta_bits, *, n_out: int, L: int,
     need = (tiles - 1) * B + rows
     bn = min(block_n, round_up(c, 128))
     c_pad = round_up(c, bn)
-    if need > rows_in or c_pad != c:
-        x3d = jnp.pad(x3d, ((0, 0), (0, max(0, need - rows_in)),
-                            (0, c_pad - c)))
     reps = B // L
-    values = jnp.tile(values, (reps, 1))
-    meta_bits = jnp.tile(meta_bits, (reps, 1))
+    with jax.named_scope(KERNEL_PREP):
+        if need > rows_in or c_pad != c:
+            x3d = jnp.pad(x3d, ((0, 0), (0, max(0, need - rows_in)),
+                                (0, c_pad - c)))
+        values = jnp.tile(values, (reps, 1))
+        meta_bits = jnp.tile(meta_bits, (reps, 1))
     compute = jnp.dtype(compute_dtype) if compute_dtype else None
     kern = functools.partial(_fused_kernel, tiles=tiles, L=L, rows=rows,
                              bn=bn, star_fast=star_fast, compute=compute)
     y = pl.pallas_call(
         kern,
+        name="sptc_star" if star_fast else "sptc_onehot",
         grid=(nb, c_pad // bn, tiles),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),             # input in HBM
@@ -241,7 +249,8 @@ def _sptc_fused_jit(x3d, values, meta_bits, *, n_out: int, L: int,
         ],
         interpret=interpret,
     )(x3d, values, meta_bits)
-    return y[:, :n_out, :c]
+    with jax.named_scope(KERNEL_PREP):
+        return y[:, :n_out, :c]
 
 
 def sptc_fused_call(values, meta_bits, x2d, *, n_out: int, L: int,

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.scopes import KERNEL_PREP
 from repro.kernels import common
 
 
@@ -69,11 +70,13 @@ def _stencil2d_jit(x, *, taps, rh: int, rw: int, th: int, tw: int,
     h_need = (grid_h - 1) * th + rows
     w_need = (grid_w - 1) * tw + cols
     if h_need > h_in or w_need > w_in:
-        x = jnp.pad(x, ((0, 0), (0, max(0, h_need - h_in)),
-                        (0, max(0, w_need - w_in))))
+        with jax.named_scope(KERNEL_PREP):
+            x = jnp.pad(x, ((0, 0), (0, max(0, h_need - h_in)),
+                            (0, max(0, w_need - w_in))))
     y = pl.pallas_call(
         functools.partial(_stencil_kernel, taps=taps, th=th, tw=tw,
                           rows=rows, cols=cols),
+        name="stencil_direct",
         grid=(nb, grid_h, grid_w),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((None, th, tw), lambda b, i, j: (b, i, j)),
@@ -85,7 +88,8 @@ def _stencil2d_jit(x, *, taps, rh: int, rw: int, th: int, tw: int,
         ],
         interpret=interpret,
     )(x)
-    return y[:, :h_out, :w_out]
+    with jax.named_scope(KERNEL_PREP):
+        return y[:, :h_out, :w_out]
 
 
 def stencil2d_call(x, *, taps, rh: int, rw: int, th: int = 128,

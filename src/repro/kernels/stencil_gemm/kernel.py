@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.scopes import KERNEL_PREP
 from repro.kernels import common
 from repro.kernels.common import round_up
 
@@ -37,10 +38,13 @@ def _windows_gemm_jit(km, windows, *, block_n: int, interpret: bool):
         raise ValueError(f"K mismatch {k2} vs {k}")
     bn = min(block_n, round_up(c, 128))
     c_pad = round_up(c, bn)
-    if c_pad != c:
-        windows = jnp.pad(windows, ((0, 0), (0, 0), (0, c_pad - c)))
+    with jax.named_scope(KERNEL_PREP):
+        if c_pad != c:
+            windows = jnp.pad(windows, ((0, 0), (0, 0), (0, c_pad - c)))
+        km = km.astype(windows.dtype)
     y = pl.pallas_call(
         _gemm_kernel,
+        name="mxu_windows",
         grid=(t, c_pad // bn),
         in_specs=[
             pl.BlockSpec((l, k), lambda i, j: (0, 0)),
@@ -49,8 +53,9 @@ def _windows_gemm_jit(km, windows, *, block_n: int, interpret: bool):
         out_specs=pl.BlockSpec((1, l, bn), lambda i, j: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((t, l, c_pad), windows.dtype),
         interpret=interpret,
-    )(km.astype(windows.dtype), windows)
-    return y[:, :, :c]
+    )(km, windows)
+    with jax.named_scope(KERNEL_PREP):
+        return y[:, :, :c]
 
 
 def windows_gemm_call(km, windows, *, block_n: int = 512,

@@ -201,31 +201,6 @@ def from_compiled(compiled, *, arch: str, cell: str, mesh_name: str,
 
 
 # ---------------------------------------------------------------------------
-# Kernel-level roofline (no collectives at kernel scope)
-# ---------------------------------------------------------------------------
-
-def kernel_roofline_time(flops: float, hbm_bytes: float, *,
-                         chips: int = 1) -> float:
-    """max(compute, memory) time for one kernel on the target hardware.
-
-    The two-term roofline for a single-chip kernel: whichever of the MXU
-    FLOP rate and the HBM stream rate binds.  Used by kernel_bench to
-    report how close a measured kernel runs to the TPU v5e hardware limit.
-    """
-    t_compute = flops / (chips * PEAK_FLOPS)
-    t_memory = hbm_bytes / (chips * HBM_BW)
-    return max(t_compute, t_memory)
-
-
-def attained_fraction(measured_s: float, flops: float, hbm_bytes: float, *,
-                      chips: int = 1) -> float:
-    """roofline_time / measured_time — 1.0 means running at the roofline."""
-    if measured_s <= 0:
-        return 0.0
-    return kernel_roofline_time(flops, hbm_bytes, chips=chips) / measured_s
-
-
-# ---------------------------------------------------------------------------
 # MODEL_FLOPS (6ND) helpers
 # ---------------------------------------------------------------------------
 

@@ -10,18 +10,20 @@ serving path (``vmap``) and the 2x2 halo-exchange engine use.
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.core.engine import StencilEngine
-from repro.core.stencil import make_stencil
+from repro.core.stencil import make_stencil, paper_suite
 from repro.distributed.halo import ShardedStencilEngine, grid_mesh
 from repro.kernels.common import INTERPRET_ENV_VAR
 from repro.vet.lowering import collective_counts
 
-SIDE = 10240            # the paper's 2-D grid (benchmarks/fig9_throughput.py)
+SIDE = 10240            # the paper's 2-D grid (bench/configs/*.json)
 
 
 @pytest.fixture(scope="module")
@@ -97,3 +99,59 @@ def test_sharded_iterate_compiles_for_v5e_2x2(topo, tpu_compile):
     assert "tpu_custom_call" in text
     counts = collective_counts(text)
     assert counts["collective-permute"] > 0 and counts["gather-like"] == 0
+
+
+# -- the benchmark's timed programs, stage by stage ---------------------------
+
+#: what runs once per step or per call without doing work on the grid
+_NO_WORK = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast",
+            "while", "conditional", "call"}
+
+
+def _cell_program(cell, topo):
+    """A cell's timed program compiled as its entry builds it
+    (``bench/entries/iterate*.py``): the iterate under one jit, state donated."""
+    from bench import harness
+    bm = harness.load_benchmark()
+    wl = harness.load_workload_file(cell)
+    cfg = harness.load_config(bm, harness.find_workload(bm, cell)["config"])
+    spec = next(s for s in paper_suite() if s.name == cfg["spec"])
+    n, spc = int(wl["grid"][0]), int(wl["steps_per_call"])
+    if "mesh" in wl:
+        mesh = grid_mesh(tuple(wl["mesh"]), devices=topo.devices)
+        eng = ShardedStencilEngine(spec, mesh, backend=wl["backend"])
+        u = jax.ShapeDtypeStruct((n, n), jnp.float32,
+                                 sharding=NamedSharding(mesh, P(*mesh.axis_names)))
+    else:
+        eng = StencilEngine(spec, backend=wl["backend"])
+        u = jax.ShapeDtypeStruct((n + 2 * spec.radius,) * 2, jnp.float32,
+                                 sharding=SingleDeviceSharding(topo.devices[0]))
+    return jax.jit(lambda v: eng.iterate(v, spc), donate_argnums=0
+                   ).lower(u).compile().as_text()
+
+
+@pytest.mark.parametrize("cell,kernel", [
+    ("box-2d49p.iterate", "sptc_onehot"),
+    ("heat-2d.iterate", "sptc_star"),
+    ("heat-2d.iterate-2x2", "sptc_star"),
+])
+def test_cell_program_is_covered_by_stages(cell, kernel, topo, tpu_compile):
+    """Every instruction of the timed program that works on the grid is a
+    kernel, a collective or counts under a stage the program declares
+    (``bench/scopes.py``), and the fused kernel carries its path's name."""
+    from bench import scopes, trace
+    text = _cell_program(cell, topo)
+    kernels, collectives = trace.classify_hlo([text])
+    assert kernels and {k.rsplit(".", 1)[0] for k in kernels} == {kernel}
+    instrs = scopes.parse([text])
+    where = scopes.attribute([text])
+    entry = re.search(r"^ENTRY %(\S+)", text, re.M).group(1)
+    run = {entry} | set(re.findall(r"body=%([^\s,}]+)", text))
+    scalar = set(re.findall(r"^\s*(?:ROOT\s+)?%(\S+) = \w+\[\]", text, re.M))
+    work = [i for i in instrs.values()
+            if i.comp in run and i.opcode not in _NO_WORK and i.name not in scalar]
+    stray = [(i.name, i.opcode, i.op_name) for i in work
+             if i.name not in kernels and i.name not in collectives
+             and where[i.name] == scopes.UNSCOPED]
+    assert len(work) > len(kernels) and not stray, stray
+    assert {where[c] for c in collectives} <= {"halo.exchange"}
