@@ -33,12 +33,21 @@ over the banded value layout, touching no metadata at all.
 
 Blocking: the compressed operand (M = L, K/2) and metadata words are tiny
 and live whole in VMEM; the input stays in HBM (``pl.ANY``) because the
-overlapping windows cannot be expressed as disjoint BlockSpec tiles.  To
-fit v5e's (8, 128) f32 tile, one grid step stacks ``B // L`` row tiles of
-``L`` (``B`` = the least multiple of ``L`` filling whole sublane tiles: 8
-rows for L = 4, 24 for L = 6) and DMAs ``round_up(B + L, 8)`` window rows
-at row ``t·B``; outputs are tiled (B, bn) with N in 128-lane multiples.
-A leading batch grid axis serves ``vmap`` (``common.fold_vmap``).
+overlapping windows cannot be expressed as disjoint BlockSpec tiles.  One
+grid step stacks ``B // L`` row tiles of ``L`` output rows, DMAs the
+``rows`` window rows they read (``B + L``, rounded up to the sublane tile)
+from row ``t·B``, and writes a (B, bn) output tile; ``B`` is a multiple of
+``lcm(L, sublane tile)`` (8 rows of f32, 16 of bf16), so every block and
+DMA offset is tile-aligned.  The one-hot path sizes the step for the MXU:
+``B`` is the largest such multiple whose window fits one 128-row MXU
+contraction tile (120 rows for L = 4, 6, 8 in f32; 112 for L = 8 in
+bf16), clamped to the output's extent, so one ``(B, rows) x (rows, bn)``
+dot serves ~120 streamed rows per latched weight chunk and reads each
+input row ~1.07 times; ``bn`` splits the lane extent into equal
+128-multiples of at most 2048.  The star path keeps its 8-row tiles (the
+least multiple of ``L`` filling whole sublane tiles: 8 rows for L = 4, 24
+for L = 6) and 512-lane blocks for now.  A leading batch grid axis serves
+``vmap`` (``common.fold_vmap``).
 
 Both ``*_call`` entry points resolve ``interpret=None`` through
 ``common.default_interpret()`` at call time: compiled Mosaic on a real
@@ -121,7 +130,8 @@ def sptc_spmm_call(values, meta, x, *, block_n: int = 512,
 # v2: fused window-DMA + in-kernel swap/gather + MXU matmul
 # ---------------------------------------------------------------------------
 
-def _fused_kernel(x_hbm, vals_ref, meta_ref, y_ref, scratch, sem, *,
+def _fused_kernel(x_hbm, vals_ref, meta_ref, y_ref, scratch, sem,
+                  *onehot_scratch,
                   tiles: int, L: int, rows: int, bn: int, star_fast: bool,
                   compute):
     """One grid step computes ``B`` output rows — ``B // L`` stacked row
@@ -129,14 +139,16 @@ def _fused_kernel(x_hbm, vals_ref, meta_ref, y_ref, scratch, sem, *,
     starting at row ``t·B``.
 
     ``B`` and ``rows`` are multiples of the sublane tile, so the output
-    block, the window DMA and its row offset are all (8, 128)-aligned;
-    ``vals_ref`` / ``meta_ref`` hold the operand's L rows repeated per
-    stacked tile (``B`` rows).
+    block, the window DMA and its row offset are all (8, 128)-aligned.
+    On the star path ``vals_ref`` / ``meta_ref`` hold the operand's L rows
+    repeated per stacked tile (``B`` rows); the one-hot path takes the L
+    rows once and stacks them as it decompresses into its one VMEM scratch
+    buffer, the (B, rows) operand ``w_ref``.
     """
     b = pl.program_id(0)
     j = pl.program_id(1)
     t = pl.program_id(2)
-    B, kh = vals_ref.shape
+    B, kh = y_ref.shape[0], vals_ref.shape[1]
 
     def dma(slot, tt):
         return pltpu.make_async_copy(
@@ -171,14 +183,41 @@ def _fused_kernel(x_hbm, vals_ref, meta_ref, y_ref, scratch, sem, *,
                 win[jj:jj + B, :].astype(jnp.float32)
         y_ref[:] = acc.astype(y_ref.dtype)
         return
+    (w_ref,) = onehot_scratch
+
+    # the decompressed operand depends only on the values and metadata:
+    # build it once per sweep of the sequential row-tile axis
+    @pl.when(t == 0)
+    def _():
+        w_ref[:] = _decompress(vals, meta_ref[:], B, L, rows).astype(
+            w_ref.dtype)
+
+    y_ref[:] = jnp.dot(w_ref[:], win, precision=common.dot_precision(win.dtype),
+                       preferred_element_type=jnp.float32
+                       ).astype(y_ref.dtype)
+
+
+def _decompress(vals, words, B: int, L: int, rows: int):
+    """Dense (B, rows) band of ``B // L`` stacked tiles of the operand,
+    from its (L, K/2) values and (L, nwords) packed metadata words."""
+    kh = vals.shape[1]
     # unpack the 2-bit metadata from the packed words in-register
-    words = meta_ref[:]                      # (B, nwords) uint32
-    jj = jax.lax.broadcasted_iota(jnp.int32, (B, kh), 1)
-    exp = jnp.zeros((B, kh), jnp.uint32)
+    jl = jax.lax.broadcasted_iota(jnp.int32, (L, kh), 1)
+    exp = jnp.zeros((L, kh), jnp.uint32)
     for w in range(words.shape[1]):
-        exp = jnp.where(jj // 16 == w, words[:, w:w + 1], exp)
-    meta = (jax.lax.shift_right_logical(exp, (2 * (jj % 16)).astype(
+        exp = jnp.where(jl // 16 == w, words[:, w:w + 1], exp)
+    meta_l = (jax.lax.shift_right_logical(exp, (2 * (jl % 16)).astype(
         jnp.uint32)) & 3).astype(jnp.int32)
+    # stack the operand's L rows once per tile of the step
+    i = jax.lax.broadcasted_iota(jnp.int32, (B, kh), 0)
+    tile = i // L
+    stacked = jnp.zeros((B, kh), jnp.float32)
+    meta = jnp.zeros((B, kh), jnp.int32)
+    for l in range(L):
+        row = i - tile * L == l
+        stacked = jnp.where(row, vals[l:l + 1, :], stacked)
+        meta = jnp.where(row, meta_l[l:l + 1, :], meta)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (B, kh), 1)
     gidx = 4 * (jj // 2) + meta              # swapped-window position
     # strided swap folded into the decompression positions: the swap
     # "odd p exchanges halves" is an involution, so swapped position g
@@ -186,52 +225,75 @@ def _fused_kernel(x_hbm, vals_ref, meta_ref, y_ref, scratch, sem, *,
     # zero stores (§3.3).  Stacked tile s reads its window from row s·L.
     kpos = jnp.where(gidx % 2 == 1,
                      jnp.where(gidx < L, gidx + L, gidx - L), gidx)
-    i = jax.lax.broadcasted_iota(jnp.int32, (B, kh), 0)
-    col = (i // L) * L + kpos                # (B, K/2) window column
+    col = tile * L + kpos                    # (B, K/2) window column
     q = jax.lax.broadcasted_iota(jnp.int32, (B, rows), 1)
     w_dense = jnp.zeros((B, rows), jnp.float32)
     for s in range(kh):                      # one-hot decompression
         w_dense = w_dense + jnp.where(q == col[:, s:s + 1],
-                                      vals[:, s:s + 1], 0.0)
-    w_dense = w_dense.astype(win.dtype)
-    y_ref[:] = jnp.dot(w_dense, win, precision=common.dot_precision(win.dtype),
-                       preferred_element_type=jnp.float32
-                       ).astype(y_ref.dtype)
+                                      stacked[:, s:s + 1], 0.0)
+    return w_dense
 
 
-def _fused_geometry(L: int, dtype) -> "tuple[int, int]":
-    """(output rows per grid step ``B``, window rows) for a fused call.
+#: widest lane block of a one-hot grid step
+_ONEHOT_MAX_BN = 2048
 
-    ``B`` is the least multiple of ``L`` that fills whole sublane tiles
-    (8 rows of f32, 16 of bf16); the window covers the ``B + L`` rows those
-    outputs read, rounded up to the sublane tile.
+
+def _sublanes(dtype) -> int:
+    """Rows of one (sublane, 128) VMEM tile of ``dtype``: 8 f32, 16 bf16."""
+    return 8 * 4 // jnp.dtype(dtype).itemsize
+
+
+def _fused_geometry(L: int, n_out: int, c: int, dtype, compute,
+                    star_fast: bool) -> "tuple[int, int, int]":
+    """(output rows per grid step ``B``, window rows, lane block ``bn``).
+
+    ``B`` is a multiple of ``lcm(L, sub)``, ``sub`` the sublane tile of the
+    input ``dtype`` (and, on the one-hot path, of the ``compute`` dtype);
+    the window covers the ``B + L`` rows those outputs read, rounded up to
+    ``sub``.  The star path takes the least such ``B`` and 512 lanes.  The
+    one-hot path takes the largest ``B`` whose window fits one MXU
+    contraction tile, then the least ``B`` that covers ``n_out`` in as many
+    steps, so a short output gets one step of ``round_up(n_out, lcm)``
+    rows; ``bn`` splits the lane extent into equal 128-multiples of at most
+    ``_ONEHOT_MAX_BN``.
     """
-    sub = 8 * 4 // jnp.dtype(dtype).itemsize
-    B = L * sub // math.gcd(L, sub)
-    return B, round_up(B + L, sub)
+    lanes = round_up(c, common.LANES)
+    if star_fast:
+        sub = _sublanes(dtype)
+        B = L * sub // math.gcd(L, sub)
+        return B, round_up(B + L, sub), min(512, lanes)
+    sub = max(_sublanes(dtype), _sublanes(compute or dtype))
+    m = L * sub // math.gcd(L, sub)
+    B = max(m, (common.MXU - L) // m * m)
+    B = round_up(-(-n_out // -(-n_out // B)), m)
+    nj = -(-lanes // _ONEHOT_MAX_BN)
+    bn = round_up(-(-lanes // nj), common.LANES)
+    return B, round_up(B + L, sub), bn
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "n_out", "L", "block_n", "star_fast", "compute_dtype", "interpret"))
+    "n_out", "L", "star_fast", "compute_dtype", "interpret"))
 def _sptc_fused_jit(x3d, values, meta_bits, *, n_out: int, L: int,
-                    block_n: int, star_fast: bool, compute_dtype,
-                    interpret: bool):
+                    star_fast: bool, compute_dtype, interpret: bool):
     nb, rows_in, c = x3d.shape
-    B, rows = _fused_geometry(L, x3d.dtype)
+    compute = jnp.dtype(compute_dtype) if compute_dtype else None
+    B, rows, bn = _fused_geometry(L, n_out, c, x3d.dtype, compute, star_fast)
     tiles = -(-n_out // B)
     need = (tiles - 1) * B + rows
-    bn = min(block_n, round_up(c, 128))
     c_pad = round_up(c, bn)
-    reps = B // L
     with jax.named_scope(KERNEL_PREP):
         if need > rows_in or c_pad != c:
             x3d = jnp.pad(x3d, ((0, 0), (0, max(0, need - rows_in)),
                                 (0, c_pad - c)))
-        values = jnp.tile(values, (reps, 1))
-        meta_bits = jnp.tile(meta_bits, (reps, 1))
-    compute = jnp.dtype(compute_dtype) if compute_dtype else None
+        if star_fast:
+            values = jnp.tile(values, (B // L, 1))
+            meta_bits = jnp.tile(meta_bits, (B // L, 1))
     kern = functools.partial(_fused_kernel, tiles=tiles, L=L, rows=rows,
                              bn=bn, star_fast=star_fast, compute=compute)
+    scratch = [pltpu.VMEM((2, rows, bn), x3d.dtype),
+               pltpu.SemaphoreType.DMA((2,))]
+    if not star_fast:
+        scratch.append(pltpu.VMEM((B, rows), compute or x3d.dtype))
     y = pl.pallas_call(
         kern,
         name="sptc_star" if star_fast else "sptc_onehot",
@@ -243,10 +305,7 @@ def _sptc_fused_jit(x3d, values, meta_bits, *, n_out: int, L: int,
         ],
         out_specs=pl.BlockSpec((None, B, bn), lambda b, j, t: (b, t, j)),
         out_shape=jax.ShapeDtypeStruct((nb, tiles * B, c_pad), x3d.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, rows, bn), x3d.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
+        scratch_shapes=scratch,
         interpret=interpret,
     )(x3d, values, meta_bits)
     with jax.named_scope(KERNEL_PREP):
@@ -254,7 +313,7 @@ def _sptc_fused_jit(x3d, values, meta_bits, *, n_out: int, L: int,
 
 
 def sptc_fused_call(values, meta_bits, x2d, *, n_out: int, L: int,
-                    block_n: int = 512, star_fast: bool = False,
+                    star_fast: bool = False,
                     compute_dtype: str | None = None,
                     interpret: bool | None = None):
     """Fused stencil SpMM: y[i] = sum_j band(i, j) * x2d[i + ...].
@@ -270,6 +329,6 @@ def sptc_fused_call(values, meta_bits, x2d, *, n_out: int, L: int,
     if interpret is None:
         interpret = common.default_interpret()
     call = functools.partial(_sptc_fused_jit, n_out=n_out, L=L,
-                             block_n=block_n, star_fast=star_fast,
+                             star_fast=star_fast,
                              compute_dtype=compute_dtype, interpret=interpret)
     return common.fold_vmap(call)(x2d, values, meta_bits)

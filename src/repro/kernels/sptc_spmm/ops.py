@@ -34,7 +34,7 @@ def sptc_spmm_windows(values, meta, windows, *, block_n: int = 512,
 
 
 def sptc_spmm_fused(operand: Sparse24, perm, x2d, *, n_out: int, L: int,
-                    star_fast: "bool | str" = "auto", block_n: int = 512,
+                    star_fast: "bool | str" = "auto",
                     compute_dtype: Optional[str] = None,
                     interpret: bool | None = None):
     """One fused Pallas program: window DMA → in-kernel swap+gather → MXU.
@@ -66,6 +66,6 @@ def sptc_spmm_fused(operand: Sparse24, perm, x2d, *, n_out: int, L: int,
                       else operand.values)
     return sptc_fused_call(
         jnp.asarray(vals, dtype=x2d.dtype), meta_bits, x2d,
-        n_out=n_out, L=L, block_n=block_n,
+        n_out=n_out, L=L,
         star_fast=fast_vals is not None,
         compute_dtype=compute_dtype, interpret=interpret)

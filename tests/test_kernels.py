@@ -1,5 +1,7 @@
 """Per-kernel Pallas (interpret=True) vs ref.py oracle sweeps over
 shapes & dtypes, per the kernel deliverable contract."""
+import math
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -68,8 +70,7 @@ def test_sptc_fused_general_vs_direct(r, c, rng):
     n_out = 3 * sk.L + 2
     x = rng.normal(size=(n_out + 2 * r, c)).astype(np.float32)
     got = sptc_spmm_fused(sk.sparse, sk.perm, jnp.asarray(x), n_out=n_out,
-                          L=sk.L, star_fast=False, block_n=256,
-                          interpret=True)
+                          L=sk.L, star_fast=False, interpret=True)
     np.testing.assert_allclose(np.asarray(got), _direct_1d(w, x, n_out),
                                rtol=2e-5, atol=2e-5)
 
@@ -110,6 +111,73 @@ def test_sptc_fused_rejects_non_swap_perm(rng):
     x = jnp.asarray(rng.normal(size=(20, 64)), jnp.float32)
     with pytest.raises(ValueError, match="strided-swap"):
         sptc_spmm_fused(sk.sparse, np.arange(2 * sk.L), x, n_out=8, L=sk.L)
+
+
+def _star_geometry_8row(L, sub):
+    """The fused kernel's (B, rows) before the one-hot path took MXU-sized
+    steps; the star path keeps it."""
+    B = L * sub // math.gcd(L, sub)
+    return B, -(-(B + L) // sub) * sub
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("L", [4, 6, 8])
+@pytest.mark.parametrize("n_out,c", [("small", 64), (10240, 10240),
+                                     (1000, 2200)])
+def test_sptc_fused_geometry(L, dtype, n_out, c):
+    from repro.kernels.sptc_spmm.kernel import _fused_geometry
+    n_out = 3 * L + 2 if n_out == "small" else n_out
+    sub = 32 // jnp.dtype(dtype).itemsize
+    m = L * sub // math.gcd(L, sub)
+    B, rows, bn = _fused_geometry(L, n_out, c, dtype, None, star_fast=False)
+    assert B % m == 0 and rows % sub == 0
+    assert B + L <= rows <= 128                   # one MXU contraction tile
+    tiles = -(-n_out // B)
+    assert (tiles - 1) * B < n_out <= tiles * B
+    if n_out < 128:                               # clamped to the output
+        assert B == -(-n_out // m) * m
+    else:                                         # the widest B's steps
+        assert tiles == -(-n_out // ((128 - L) // m * m))
+    lane_blocks = -(-c // bn)                     # equal 128-lane multiples
+    assert bn % 128 == 0 and bn <= 2048 and lane_blocks * bn - c < 128 * lane_blocks
+    sB, srows, sbn = _fused_geometry(L, n_out, c, dtype, None, star_fast=True)
+    assert (sB, srows) == _star_geometry_8row(L, sub)
+    assert sbn == min(512, -(-c // 128) * 128)
+
+
+def test_sptc_fused_geometry_box_cell():
+    """Box-2D49P's row op at 10240²: 120-row steps from a 128-row window,
+    2048 lanes: 86 × 5 grid steps where 8-row steps took 1280 × 20."""
+    from repro.kernels.sptc_spmm.kernel import _fused_geometry
+    assert _fused_geometry(8, 10240, 10240, jnp.float32, None,
+                           star_fast=False) == (120, 128, 2048)
+    assert _fused_geometry(8, 10240, 10240, jnp.float32, "bfloat16",
+                           star_fast=False)[:2] == (112, 128)
+
+
+@pytest.mark.parametrize("r,batch", [(1, None), (2, None), (3, None),
+                                     (2, 2)])
+def test_sptc_fused_onehot_row_blocks_vs_direct(r, batch, rng):
+    """Several MXU-sized row blocks with a ragged tail, and a lane extent
+    that is no multiple of the lane block; ``batch`` runs through ``vmap``
+    (the kernel's batch grid axis)."""
+    import jax
+    from repro.kernels.sptc_spmm.kernel import _fused_geometry
+    from repro.kernels.sptc_spmm.ops import sptc_spmm_fused
+    w = rng.normal(size=2 * r + 1)
+    sk = sparsify_stencil_kernel(w)
+    n_out, c = 397, 2200
+    B, _, bn = _fused_geometry(sk.L, n_out, c, jnp.float32, None, False)
+    assert -(-n_out // B) >= 3 and n_out % B and c % bn and c > bn
+    lead = () if batch is None else (batch,)
+    x = rng.normal(size=lead + (n_out + 2 * r, c)).astype(np.float32)
+    call = lambda v: sptc_spmm_fused(sk.sparse, sk.perm, v, n_out=n_out,
+                                     L=sk.L, star_fast=False, interpret=True)
+    got = np.asarray(call(jnp.asarray(x)) if batch is None
+                     else jax.vmap(call)(jnp.asarray(x)))
+    want = np.stack([_direct_1d(w, xb, n_out) for xb in x.reshape(
+        (-1,) + x.shape[-2:])]).reshape(lead + (n_out, c))
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -10,6 +10,7 @@ serving path (``vmap``) and the 2x2 halo-exchange engine use.
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
+import math
 import re
 
 import jax
@@ -108,8 +109,8 @@ _NO_WORK = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast",
             "while", "conditional", "call"}
 
 
-def _cell_program(cell, topo):
-    """A cell's timed program compiled as its entry builds it
+def _cell_trace(cell, topo):
+    """A cell's timed program traced as its entry builds it
     (``bench/entries/iterate*.py``): the iterate under one jit, state donated."""
     from bench import harness
     bm = harness.load_benchmark()
@@ -126,8 +127,38 @@ def _cell_program(cell, topo):
         eng = StencilEngine(spec, backend=wl["backend"])
         u = jax.ShapeDtypeStruct((n + 2 * spec.radius,) * 2, jnp.float32,
                                  sharding=SingleDeviceSharding(topo.devices[0]))
-    return jax.jit(lambda v: eng.iterate(v, spc), donate_argnums=0
-                   ).lower(u).compile().as_text()
+    return jax.jit(lambda v: eng.iterate(v, spc), donate_argnums=0).trace(u)
+
+
+def _cell_program(cell, topo):
+    """A cell's timed program, compiled."""
+    return _cell_trace(cell, topo).lower().compile().as_text()
+
+
+def _pallas_grids(jaxpr):
+    """(kernel name, grid) of every ``pallas_call`` in ``jaxpr``, nested
+    jaxprs (jit, scan, custom_vmap) included."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["name"], eqn.params["grid_mapping"].grid
+            continue
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                if isinstance(sub, ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, Jaxpr):
+                    yield from _pallas_grids(sub)
+
+
+def test_box_cell_onehot_grid_fills_the_mxu(topo, tpu_compile):
+    """The box cell's one-hot kernels take MXU-sized grid steps: at most
+    1/8 of the 25,600 steps a call that 8-row, 512-lane steps took."""
+    jaxpr = _cell_trace("box-2d49p.iterate", topo).jaxpr.jaxpr
+    grids = list(_pallas_grids(jaxpr))
+    assert len(grids) == 7 and {n for n, _ in grids} == {"sptc_onehot"}
+    for _, grid in grids:
+        assert math.prod(grid) <= 25600 // 8, grid
 
 
 @pytest.mark.parametrize("cell,kernel", [
