@@ -25,7 +25,8 @@ from repro.tuner.search import measure
 
 
 def _input(spec, size, rng):
-    dims = {1: (size * size,), 2: (size, size)}[spec.ndim]
+    side3 = max(8, round(size ** (2 / 3)))      # about size^2 points in 3-D
+    dims = {1: (size * size,), 2: (size, size), 3: (side3,) * 3}[spec.ndim]
     shape = tuple(s + 2 * spec.radius for s in dims)
     return jnp.asarray(rng.normal(size=shape), jnp.float32)
 

@@ -24,7 +24,12 @@ Two workload classes ride on IR-level attributes:
     ``r`` per step, and ``iterate`` advances ``k`` steps per scan iteration.
 
 Input convention: ``x`` carries the halo — shape (N1+2kr, ..., Nd+2kr) for a
-k-step engine — and the output is the (N1, ..., Nd) interior update.
+k-step engine — and the output is the (N1, ..., Nd) interior update.  A
+``"planes"`` plan (3-D, radius 1, on ``pallas_sptc``) steps the
+halo-inclusive state in place: its one kernel a step writes the next state,
+zero halo included, so ``iterate`` carries it with no crop, re-pad or
+relayout.  Planes too wide for the kernel's VMEM run its row ops one by one
+and re-pad, as a ``"rows"`` plan does.
 """
 from __future__ import annotations
 
@@ -262,9 +267,12 @@ def _op_slice(mode: str, op: RowOp, out_shape: Tuple[int, ...], r: int,
         sl = tuple(slice(None) if a == op.axis else slice(r, r + out_shape[a])
                    for a in range(d))
         return sl, op.axis
-    sl = tuple(slice(u, u + out_shape[a])
-               for a, u in enumerate(op.lead)) + (slice(None),)
-    return sl, d - 1
+    # rows and planes: ``lead`` offsets every axis but the op's own
+    lead = list(op.lead)
+    lead.insert(op.axis, None)
+    sl = tuple(slice(None) if u is None else slice(u, u + out_shape[a])
+               for a, u in enumerate(lead))
+    return sl, op.axis
 
 
 def _emit_const(plan: LoweredPlan) -> ApplyFn:
@@ -406,8 +414,53 @@ def _emit_var(plan: LoweredPlan) -> ApplyFn:
     return fn
 
 
+def _emit_planes(plan: LoweredPlan, halo: bool) -> ApplyFn:
+    """``"planes"`` emission: with ``halo``, the halo-inclusive state to
+    the next one (zero halo); else the interior update.
+
+    Where the state's planes fit the kernel's VMEM, one ``sptc_onehot3d``
+    program runs every row op of the step from one window of the state
+    and writes the whole next state, zero halo and all.  Wider planes run
+    the same row ops one by one along ``y`` (the ``rows`` emission).
+    """
+    from repro.kernels.sptc_spmm.kernel import planes_fit
+    from repro.kernels.sptc_spmm.ops import sptc_planes
+    sp = plan.sparsify
+    assert sp is not None
+    ops = plan.decompose.ops
+    operands = [sp.operands[op.operand] for op in ops]
+    leads = [op.lead for op in ops]
+    r = plan.spec.radius
+    one_by_one = _emit_const(plan)
+
+    def fn(x: jnp.ndarray) -> jnp.ndarray:
+        if not planes_fit(x.shape, x.dtype, r, len(ops)):
+            y = one_by_one(x)
+            if not halo:
+                return y
+            with jax.named_scope(scopes.REPAD):
+                return jnp.pad(y, r)
+        y = sptc_planes(operands, sp.perm, x, leads=leads, L=plan.L, r=r)
+        if halo:
+            return y
+        with jax.named_scope(scopes.KERNEL_PREP):
+            return y[r:-r, r:-r, r:-r]
+    return fn
+
+
+def emit_halo_step(plan: LoweredPlan) -> Optional[ApplyFn]:
+    """The plan's step from one halo-inclusive state to the next (zero
+    halo), for plans that step the state in place; else ``None``."""
+    if plan.decompose.mode != "planes":
+        return None
+    plan.validate()
+    return _emit_planes(plan, halo=True)
+
+
 def _emit_step(plan: LoweredPlan) -> ApplyFn:
     """One stencil application from the plan's tables (temporal_steps ignored)."""
+    if plan.decompose.mode == "planes":
+        return _emit_planes(plan, halo=False)
     if plan.emit.backend == "pallas_direct":
         from repro.kernels import dispatch as kdispatch
         fn: ApplyFn = kdispatch.build(plan.spec, plan.emit.backend, plan.L)
@@ -465,6 +518,8 @@ class StencilEngine:
         self.fuse_rows = fuse_rows
         self.temporal_steps = temporal_steps
         self._fn = jax.jit(emit(self.plan_ir))
+        halo_step = emit_halo_step(self.plan_ir)
+        self._halo_step = None if halo_step is None else jax.jit(halo_step)
 
     # -- public API ----------------------------------------------------------
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
@@ -481,6 +536,18 @@ class StencilEngine:
         if steps % k != 0:
             raise ValueError(
                 f"steps={steps} must be a multiple of temporal_steps={k}")
+        if self._halo_step is not None:
+            step = self._halo_step
+
+            def in_place(x_in: jnp.ndarray, _: None
+                         ) -> Tuple[jnp.ndarray, None]:
+                return step(x_in), None
+
+            # two steps a loop iteration: the second writes into the
+            # buffer the first read, so the loop carries its state
+            # without a copy
+            out, _ = jax.lax.scan(in_place, x, None, length=steps, unroll=2)
+            return out
         pad = [(k * self.spec.radius,) * 2] * self.spec.ndim
 
         def body(x_in: jnp.ndarray, _: None) -> Tuple[jnp.ndarray, None]:

@@ -55,7 +55,7 @@ MATRIX_BACKENDS: Tuple[str, ...] = ("gemm", "sptc", "pallas_mxu",
 SPARSE_BACKENDS: Tuple[str, ...] = ("sptc", "pallas_sptc")
 
 DECOMPOSE_MODES: Tuple[str, ...] = ("single", "star-axis", "rows",
-                                    "fused-rows")
+                                    "fused-rows", "planes")
 COEFFICIENT_MODES: Tuple[str, ...] = ("const", "var")
 
 
@@ -64,9 +64,10 @@ class RowOp:
     """One 1-D stencil application the emitted program performs.
 
     ``axis`` is the input axis the 1-D kernel runs along; ``lead`` holds
-    the leading-axis slice offsets for ``"rows"``-mode decompositions
-    (empty otherwise); ``operand`` indexes this op's tables in the
-    downstream stages (kernels, matrices, sparse operands, schedules).
+    the leading-axis slice offsets for ``"rows"``-mode decompositions, and
+    the ``(z, x)`` offsets of a ``"planes"``-mode op, whose kernel runs
+    along ``y`` (empty otherwise); ``operand`` indexes this op's tables in
+    the downstream stages (kernels, matrices, sparse operands, schedules).
     """
 
     axis: int

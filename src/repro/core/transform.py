@@ -11,7 +11,10 @@ the strided-swap permutation (sparsify.py) is an involution on column pairs
 
 Higher-dimensional stencils decompose by kernel rows (paper §3.2.1): a d-D
 kernel is a sum over its leading (d-1)-D offsets of 1-D stencils applied along
-the last axis; partial results accumulate.
+the last axis; partial results accumulate.  A 3-D stencil of radius 1 on
+the fused SpTC kernel decomposes by plane columns instead
+(:func:`decompose_planes`): 1-D stencils along ``y``, one per ``(z, x)``
+offset, which all read one window of the state.
 
 :func:`lower_spec` is the front door: it runs the full ahead-of-time
 pipeline — row-decompose → kernel-matrix build → strided-swap sparsify →
@@ -75,6 +78,26 @@ def decompose_rows(spec: StencilSpec) -> List[Tuple[Tuple[int, ...], np.ndarray]
         row = w[lead]
         if np.any(row != 0):
             out.append((lead, row))
+    return out
+
+
+def decompose_planes(
+        spec: StencilSpec) -> List[Tuple[Tuple[int, int], np.ndarray]]:
+    """Decompose a 3-D stencil into 1-D kernels along ``y`` (axis 1).
+
+    Returns ``((dz, dx), w_1d)`` per ``(z, x)`` offset of the kernel
+    (0-based, as in :func:`decompose_rows`), ``w_1d = w[dz, :, dx]``;
+    all-zero columns are dropped.  Every op reads the same ``y`` window
+    of the planes ``z + dz - r``, shifted by ``dx - r`` along ``x``.
+    """
+    if spec.ndim != 3:
+        raise ValueError(f"plane decomposition is 3-D, got {spec.ndim}-D")
+    w = spec.weights
+    out: List[Tuple[Tuple[int, int], np.ndarray]] = []
+    for dz, dx in np.ndindex(w.shape[0], w.shape[2]):
+        col = w[dz, :, dx]
+        if np.any(col != 0):
+            out.append(((dz, dx), col))
     return out
 
 
@@ -252,6 +275,14 @@ def lower_spec(spec: StencilSpec, backend: str = "direct",
             ops.append(ir.RowOp(axis=d - 1, lead=lead, operand=i))
             kernels.append(ones)
             slabs.append(slab)
+    elif (d == 3 and r == 1 and backend == "pallas_sptc"
+          and temporal_steps == 1):
+        # one fused program a step: every op reads one window of the state
+        # (its (2r+1)^2 unrolled dots a row tile outgrow VMEM at r >= 2)
+        mode = "planes"
+        for i, (lead, w_1d) in enumerate(decompose_planes(spec)):
+            ops.append(ir.RowOp(axis=1, lead=lead, operand=i))
+            kernels.append(w_1d)
     else:
         mode = "fused-rows" if (fuse_rows and d == 2
                                 and backend in ("gemm", "sptc")) else "rows"

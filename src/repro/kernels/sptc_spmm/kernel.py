@@ -197,9 +197,13 @@ def _fused_kernel(x_hbm, vals_ref, meta_ref, y_ref, scratch, sem,
                        ).astype(y_ref.dtype)
 
 
-def _decompress(vals, words, B: int, L: int, rows: int):
+def _decompress(vals, words, B: int, L: int, rows: int, shift: int = 0):
     """Dense (B, rows) band of ``B // L`` stacked tiles of the operand,
-    from its (L, K/2) values and (L, nwords) packed metadata words."""
+    from its (L, K/2) values and (L, nwords) packed metadata words.
+
+    Output row ``i`` reads window rows ``i + shift`` onwards; a column
+    that falls outside ``[0, rows)`` is dropped.
+    """
     kh = vals.shape[1]
     # unpack the 2-bit metadata from the packed words in-register
     jl = jax.lax.broadcasted_iota(jnp.int32, (L, kh), 1)
@@ -226,6 +230,8 @@ def _decompress(vals, words, B: int, L: int, rows: int):
     kpos = jnp.where(gidx % 2 == 1,
                      jnp.where(gidx < L, gidx + L, gidx - L), gidx)
     col = tile * L + kpos                    # (B, K/2) window column
+    if shift:
+        col = col + shift
     q = jax.lax.broadcasted_iota(jnp.int32, (B, rows), 1)
     w_dense = jnp.zeros((B, rows), jnp.float32)
     for s in range(kh):                      # one-hot decompression
@@ -332,3 +338,233 @@ def sptc_fused_call(values, meta_bits, x2d, *, n_out: int, L: int,
                              star_fast=star_fast,
                              compute_dtype=compute_dtype, interpret=interpret)
     return common.fold_vmap(call)(x2d, values, meta_bits)
+
+
+# ---------------------------------------------------------------------------
+# 3-D planes: every row op of a 3-D box step in one program
+# ---------------------------------------------------------------------------
+
+#: most VMEM the planes kernel may ask for (v5e holds 128 MiB)
+_PLANES_VMEM_CAP = 100 << 20
+#: room beside the counted buffers and tile values, for Mosaic's own
+#: scratch and the rounding of its allocations
+_MOSAIC_SCRATCH = 4 << 20
+
+
+def _planes_geometry(r: int, ny: int):
+    """(output rows per tile ``B``, window rows, tile runs, band shifts).
+
+    A run ``(o, s, j, n)`` is ``n`` tiles; its ``k``-th writes rows
+    ``[o + kB, o + kB + B)`` of an output plane from the window rows
+    ``[s + kB, s + kB + rows)`` of each input plane, through the band
+    decompressed with shift ``shifts[j]``.  ``B`` is the widest multiple
+    of the sublane tile whose ``B + 2r`` window fits one MXU contraction
+    tile; tiles start on sublane tiles and cover the plane's rows rounded
+    up to a sublane tile, the last one flush with that end.  Windows start
+    ``r`` rows above their tile and are clamped into the plane, so the
+    first and last tiles read their band shifted; the tiles between them
+    form one run of shift 0, whose windows lie inside the plane's block.
+    """
+    by = round_up(ny, common.SUBLANES)
+    B = min((common.MXU - 2 * r) // common.SUBLANES * common.SUBLANES, by)
+    rows = min(round_up(B + 2 * r, common.SUBLANES), ny)
+    pre = round_up(r, common.SUBLANES)
+    runs, shifts = [], []
+    for t in range(-(-by // B)):
+        o = min(t * B, by - B)
+        s = min(max(o - r, 0), ny - rows)
+        h = o - s - r
+        for p in range(max(o, r), min(o + B, ny - r)):
+            assert s <= p - r and p + r < s + rows, (r, ny, o, s, p)
+        if h not in shifts:
+            shifts.append(h)
+        if (runs and h == 0 and shifts[runs[-1][2]] == 0
+                and o == runs[-1][0] + runs[-1][3] * B
+                and o >= pre and o + rows <= by):
+            o0, s0, j, n = runs[-1]
+            runs[-1] = (o0, s0, j, n + 1)
+        else:
+            runs.append((o, s, shifts.index(h), 1))
+    return B, rows, tuple(runs), tuple(shifts)
+
+
+def _planes_kernel(*refs, leads, r: int, L: int, B: int, rows: int, runs,
+                   shifts, ny: int, nx: int, nz: int):
+    """Output plane ``z`` of a 3-D box step, halo included.
+
+    ``refs`` opens with the ``2r + 1`` input planes ``z - r .. z + r``
+    (clamped into the grid).  Row op ``i`` with lead ``(dz, dx)`` runs the
+    operand's band down the plane's rows (``y``) of input plane ``dz``;
+    ops of one ``dx`` share a lane shift of ``r - dx``, taken once on
+    their summed dots.  Halo planes, rows and lanes are written as zeros,
+    so the output is the next step's state.
+    """
+    n_in = 2 * r + 1
+    planes = refs[:n_in]
+    vals_ref, meta_ref, y_ref, w_ref = refs[n_in:]
+    z = pl.program_id(0)
+
+    # the decompressed bands depend only on the operands: build them at
+    # the first grid step, one set per band shift
+    @pl.when(z == 0)
+    def _():
+        vals = vals_ref[:].astype(jnp.float32)
+        words = meta_ref[:]
+        for j, h in enumerate(shifts):
+            for i in range(len(leads)):
+                w_ref[j, i] = _decompress(
+                    vals[i * L:(i + 1) * L], words[i * L:(i + 1) * L],
+                    B, L, rows, h).astype(w_ref.dtype)
+
+    halo = (z < r) | (z >= nz - r)
+
+    @pl.when(halo)
+    def _():
+        y_ref[...] = jnp.zeros(y_ref.shape, y_ref.dtype)
+
+    @pl.when(jnp.logical_not(halo))
+    def _():
+        bx = y_ref.shape[1]
+        groups: dict = {}
+        for i, (dz, dx) in enumerate(leads):
+            groups.setdefault(dx, []).append((i, dz))
+        used = sorted({dz for dz, _ in leads})
+        row = jax.lax.broadcasted_iota(jnp.int32, (B, bx), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (B, bx), 1)
+        lane_ok = (lane >= r) & (lane < nx - r)
+        pre = round_up(r, common.SUBLANES)
+
+        def tile(o, wins, j):
+            acc = None
+            for dx, ops in sorted(groups.items()):
+                part = None
+                for i, dz in ops:
+                    d = jnp.dot(w_ref[j, i], wins[dz],
+                                precision=common.dot_precision(wins[dz].dtype),
+                                preferred_element_type=jnp.float32)
+                    part = d if part is None else part + d
+                # output lane x reads input lane x + dx - r
+                shift = (r - dx) % bx
+                if shift:
+                    part = pltpu.roll(part, shift, 1)
+                acc = part if acc is None else acc + part
+            ok = lane_ok & (row + o >= r) & (row + o < ny - r)
+            y_ref[pl.ds(o, B), :] = jnp.where(ok, acc, 0.0).astype(y_ref.dtype)
+
+        for o0, s0, j, n in runs:
+            if n == 1:
+                tile(o0, {dz: planes[dz][s0:s0 + rows, :] for dz in used}, j)
+                continue
+
+            # a run's windows start r rows above its tiles: load from the
+            # sublane tile above, then drop the rows before the window
+            def body(k, carry, o0=o0, j=j):
+                o = pl.multiple_of(o0 + k * B, common.SUBLANES)
+                wins = {dz: planes[dz][pl.ds(o - pre, rows + pre), :]
+                        [pre - r:pre - r + rows] for dz in used}
+                tile(o, wins, j)
+                return carry
+            jax.lax.fori_loop(0, n, body, 0)
+
+
+def _plane_index(z, *, d: int, nz: int):
+    return jnp.clip(z + d, 0, nz - 1), 0, 0
+
+
+def planes_vmem_bytes(shape, dtype, r: int, n_ops: int) -> int:
+    """Scoped VMEM the planes kernel takes on a ``(Z, Y, X)`` state.
+
+    Its double-buffered input and output planes, the decompressed bands,
+    and the f32 values of each row-tile body, which Mosaic keeps in VMEM
+    once per run of tiles (each run's body is its own code): ``n_ops +
+    2(2r + 1)`` values of ``B`` rows and the ``2r + 1`` windows, each as
+    wide as the plane.  In compiles for a v5e that bounds the kernel's
+    need on planes from 10 x 32768 and 18 x 32768 to 4096 x 258 and
+    1026 x 2050; ``_MOSAIC_SCRATCH`` is the room above it.
+    """
+    ny, nx = shape[-2:]
+    by, bx = round_up(ny, common.SUBLANES), round_up(nx, common.LANES)
+    B, rows, runs, shifts = _planes_geometry(r, ny)
+    n_in = 2 * r + 1
+    item = jnp.dtype(dtype).itemsize
+    return (2 * (n_in + 1) * by * bx * item
+            + len(shifts) * n_ops * B * rows * item
+            + len(runs) * ((n_ops + 2 * n_in) * B + n_in * rows) * bx * 4
+            + _MOSAIC_SCRATCH)
+
+
+def planes_fit(shape, dtype, r: int, n_ops: int) -> bool:
+    """Whether the planes kernel's VMEM holds a ``(Z, Y, X)`` state."""
+    return planes_vmem_bytes(shape, dtype, r, n_ops) <= _PLANES_VMEM_CAP
+
+
+@functools.partial(jax.jit, static_argnames=("leads", "L", "r", "interpret"))
+def _planes_jit(x, values, meta_bits, *, leads, L: int, r: int,
+                interpret: bool):
+    nz, ny, nx = x.shape
+    by, bx = round_up(ny, common.SUBLANES), round_up(nx, common.LANES)
+    B, rows, runs, shifts = _planes_geometry(r, ny)
+    n_in = 2 * r + 1
+    if not planes_fit(x.shape, x.dtype, r, len(leads)):
+        raise ValueError(
+            f"a {ny} x {nx} plane is too large for the planes kernel: it "
+            f"holds {2 * (n_in + 1)} planes in VMEM "
+            f"({planes_vmem_bytes(x.shape, x.dtype, r, len(leads))} bytes, "
+            f"at most {_PLANES_VMEM_CAP})")
+    kern = functools.partial(_planes_kernel, leads=leads, r=r, L=L, B=B,
+                             rows=rows, runs=runs, shifts=shifts, ny=ny,
+                             nx=nx, nz=nz)
+    plane = [pl.BlockSpec((None, by, bx),
+                          functools.partial(_plane_index, d=d - r, nz=nz))
+             for d in range(n_in)]
+    return pl.pallas_call(
+        kern,
+        name="sptc_onehot3d",
+        grid=(nz,),
+        in_specs=plane + [
+            pl.BlockSpec(values.shape, lambda z: (0, 0)),
+            pl.BlockSpec(meta_bits.shape, lambda z: (0, 0)),
+        ],
+        out_specs=pl.BlockSpec((None, by, bx), lambda z: (z, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        scratch_shapes=[pltpu.VMEM((len(shifts), len(leads), B, rows),
+                                   x.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_PLANES_VMEM_CAP),
+        interpret=interpret,
+    )(*([x] * n_in), values, meta_bits)
+
+
+def sptc_planes_call(values, meta_bits, x, *, leads, L: int, r: int,
+                     interpret: bool | None = None):
+    """One step of a 3-D stencil in the state's own layout.
+
+    ``x``         (Z + 2r, Y + 2r, X + 2r) state, halo included.
+    ``leads``     per row op, its ``(dz, dx)`` offsets (0 .. 2r): op ``i``
+                  runs along ``y`` on input plane ``z + dz - r``, lanes
+                  shifted by ``dx - r``.
+    ``values``    (n_ops * L, K/2) compressed operands, op after op.
+    ``meta_bits`` (n_ops * L, ceil(K/32)) their packed metadata words.
+    Returns the next state, the same shape as ``x``, with a zero halo.
+    ``vmap`` over ``x`` maps the call over the batch.
+    """
+    if interpret is None:
+        interpret = common.default_interpret()
+    call = functools.partial(_planes_jit, leads=tuple(leads), L=L, r=r,
+                             interpret=interpret)
+
+    @jax.custom_batching.custom_vmap
+    def f(x, values, meta_bits):
+        return call(x, values, meta_bits)
+
+    @f.def_vmap
+    def _rule(axis_size, in_batched, x, values, meta_bits):
+        if any(in_batched[1:]):
+            raise NotImplementedError(
+                "only the kernel's input array may be vmapped")
+        if not in_batched[0]:
+            x = jnp.broadcast_to(x, (axis_size,) + x.shape)
+        return jax.lax.map(lambda u: call(u, values, meta_bits), x), True
+
+    return f(x, values, meta_bits)

@@ -1,7 +1,7 @@
 """Jitted public wrappers for the 2:4 compressed SpMM kernels."""
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -9,7 +9,8 @@ import numpy as np
 
 from repro.core.sparsify import (Sparse24, contiguous_band_values,
                                  strided_swap_perm)
-from repro.kernels.sptc_spmm.kernel import sptc_fused_call, sptc_spmm_call
+from repro.kernels.sptc_spmm.kernel import (sptc_fused_call, sptc_planes_call,
+                                            sptc_spmm_call)
 
 
 def sptc_spmm(values, meta, x, *, block_n: int = 512,
@@ -33,6 +34,13 @@ def sptc_spmm_windows(values, meta, windows, *, block_n: int = 512,
     return jax.vmap(fn)(jnp.asarray(windows))
 
 
+def _check_perm(perm, L: int, who: str) -> None:
+    if not np.array_equal(np.asarray(perm), strided_swap_perm(L)):
+        raise ValueError(
+            f"{who} requires the strided-swap permutation — the kernel "
+            "derives it in closed form from an iota (§3.3)")
+
+
 def sptc_spmm_fused(operand: Sparse24, perm, x2d, *, n_out: int, L: int,
                     star_fast: "bool | str" = "auto",
                     compute_dtype: Optional[str] = None,
@@ -51,10 +59,7 @@ def sptc_spmm_fused(operand: Sparse24, perm, x2d, *, n_out: int, L: int,
     ``False`` always runs the faithful one-hot decompression.
     """
     perm = np.asarray(perm)
-    if not np.array_equal(perm, strided_swap_perm(L)):
-        raise ValueError(
-            "sptc_spmm_fused requires the strided-swap permutation — the "
-            "kernel derives it in closed form from an iota (§3.3)")
+    _check_perm(perm, L, "sptc_spmm_fused")
     fast_vals = (contiguous_band_values(operand, perm)
                  if star_fast in ("auto", True) else None)
     if star_fast is True and fast_vals is None:
@@ -69,3 +74,24 @@ def sptc_spmm_fused(operand: Sparse24, perm, x2d, *, n_out: int, L: int,
         n_out=n_out, L=L,
         star_fast=fast_vals is not None,
         compute_dtype=compute_dtype, interpret=interpret)
+
+
+def sptc_planes(operands: Sequence[Sparse24], perm, x,
+                *, leads: Sequence[Tuple[int, int]], L: int, r: int,
+                interpret: bool | None = None):
+    """One fused Pallas program a step of a 3-D stencil: every row op's
+    one-hot band on the MXU, in the state's own halo-inclusive layout.
+
+    ``operands[i]`` is the 2:4 operand of the row op with lead
+    ``leads[i] = (dz, dx)``: its 1-D kernel runs along ``y`` at those
+    ``z`` and ``x`` offsets.  The packed tables are built here in NumPy at
+    trace time.  Returns the next state, shaped as ``x``, zero halo.
+    """
+    _check_perm(perm, L, "sptc_planes")
+    x = jnp.asarray(x)
+    values = np.concatenate([np.asarray(op.values) for op in operands])
+    meta_bits = np.concatenate([op.meta_bits() for op in operands])
+    return sptc_planes_call(
+        jnp.asarray(values, dtype=x.dtype), jnp.asarray(meta_bits), x,
+        leads=tuple((int(a), int(c)) for a, c in leads), L=L, r=r,
+        interpret=interpret)

@@ -104,7 +104,7 @@ def test_bf16_inputs(backend, rng):
 
 def test_paper_suite_all_runs():
     for spec in paper_suite():
-        dims = {1: (130,), 2: (18, 22)}[spec.ndim]
+        dims = {1: (130,), 2: (18, 22), 3: (9, 10, 11)}[spec.ndim]
         x = jnp.ones(tuple(s + 2 * spec.radius for s in dims))
         y = apply_stencil(spec, x, backend="sptc")
         assert y.shape == dims
@@ -228,3 +228,117 @@ def test_temporal_iterate_matches_blockwise_reference(rng):
     np.testing.assert_allclose(got, y, rtol=1e-5, atol=1e-5)
     with pytest.raises(ValueError, match="multiple"):
         eng.iterate(jnp.asarray(x), steps=3)
+
+
+# ---------------------------------------------------------------------------
+# 3-D box on pallas_sptc: the "planes" lowering, one kernel a step
+# ---------------------------------------------------------------------------
+
+def _loop3d(w, x):
+    """NumPy triple loop over the taps of a 3-D stencil (float64)."""
+    r = (w.shape[0] - 1) // 2
+    n = tuple(s - 2 * r for s in x.shape)
+    out = np.zeros(n)
+    for a in range(2 * r + 1):
+        for b in range(2 * r + 1):
+            for c in range(2 * r + 1):
+                out += w[a, b, c] * x[a:a + n[0], b:b + n[1], c:c + n[2]]
+    return out
+
+
+@pytest.mark.parametrize("dims,r", [
+    ((5, 7, 9), 1),            # one row tile, one lane tile
+    ((7, 131, 140), 1),        # two row tiles, two 128-lane tiles
+    ((3, 484, 131), 1),        # first tile, a looped run of tiles, last tile
+    ((4, 250, 262), 1),        # three row tiles, three lane tiles
+    ((5, 25, 30), 2),          # radius 2 keeps the rows decomposition
+], ids=["tiny", "two-tiles", "tile-run", "three-tiles", "r2"])
+def test_planes_box3d_matches_direct_and_numpy(dims, r, rng):
+    """Box-3D on pallas_sptc (interpret mode) equals the direct backend and
+    a NumPy loop, on sides that are not multiples of 8 or 128."""
+    spec = make_stencil("box", 3, r, seed=52)
+    eng = StencilEngine(spec, backend="pallas_sptc")
+    assert eng.plan_ir.decompose.mode == ("planes" if r == 1 else "rows")
+    x = rng.normal(size=tuple(s + 2 * r for s in dims)).astype(np.float32)
+    got = np.asarray(eng(jnp.asarray(x)))
+    assert got.shape == dims
+    want = np.asarray(StencilEngine(spec, backend="direct")(jnp.asarray(x)))
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(got, _loop3d(spec.weights, x.astype(np.float64)),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_planes_iterate_keeps_a_zero_halo(rng):
+    """iterate on the planes path: several steps equal direct with a zero
+    re-pad after each, the first step reads the halo it was given, and the
+    halo comes out zero."""
+    spec = make_stencil("box", 3, 1, seed=52)
+    x = jnp.asarray(rng.normal(size=(9, 132, 135)), jnp.float32)
+    got = StencilEngine(spec, backend="pallas_sptc").iterate(x, 5)
+    direct = StencilEngine(spec, backend="direct")
+    want = x
+    for _ in range(5):
+        want = jnp.pad(direct(want), 1)
+    assert got.shape == x.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    halo = np.asarray(got).copy()
+    halo[1:-1, 1:-1, 1:-1] = 0.0
+    assert not halo.any()
+
+
+def test_planes_too_wide_for_vmem_run_the_row_ops(rng, monkeypatch):
+    """Planes the kernel's VMEM cannot hold run the same row ops one by one
+    along ``y``: the step and ``iterate`` still equal direct and a NumPy
+    loop, the halo stays zero, and no ``sptc_onehot3d`` call is traced.
+    The kernel itself refuses such planes."""
+    import jax
+    from repro.kernels.sptc_spmm import kernel
+    from repro.kernels.sptc_spmm.ops import sptc_planes
+    spec = make_stencil("box", 3, 1, seed=52)
+    x = jnp.asarray(rng.normal(size=(7, 133, 141)), jnp.float32)
+    # the cap one byte under what these planes take
+    monkeypatch.setattr(kernel, "_PLANES_VMEM_CAP",
+                        kernel.planes_vmem_bytes(x.shape, x.dtype, 1, 9) - 1)
+    eng = StencilEngine(spec, backend="pallas_sptc")
+    assert "sptc_onehot3d" not in str(jax.make_jaxpr(eng._halo_step)(x))
+    direct = StencilEngine(spec, backend="direct")
+    np.testing.assert_allclose(np.asarray(eng(x)), np.asarray(direct(x)),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(eng(x)), _loop3d(spec.weights, np.asarray(x, np.float64)),
+        rtol=2e-5, atol=2e-5)
+    got = np.asarray(eng.iterate(x, 3))
+    want = x
+    for _ in range(3):
+        want = jnp.pad(direct(want), 1)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=2e-5, atol=2e-5)
+    halo = got.copy()
+    halo[1:-1, 1:-1, 1:-1] = 0.0
+    assert not halo.any()
+    sp = eng.plan_ir.sparsify
+    with pytest.raises(ValueError, match="too large for the planes kernel"):
+        sptc_planes(sp.operands, sp.perm, x,
+                    leads=[op.lead for op in eng.plan_ir.decompose.ops],
+                    L=eng.L, r=1)
+
+
+def test_planes_fit_the_cell_and_not_twice_its_width():
+    """The cell's 1026^2 planes fit the kernel's VMEM; 2050^2 planes, and
+    very wide planes of few rows, do not."""
+    from repro.kernels.sptc_spmm.kernel import planes_fit
+    assert planes_fit((1026, 1026, 1026), jnp.float32, 1, 9)
+    assert not planes_fit((18, 2050, 2050), jnp.float32, 1, 9)
+    assert not planes_fit((18, 130, 16384), jnp.float32, 1, 9)
+
+
+def test_planes_vmap_matches_direct(rng):
+    """vmap over the planes engine runs one kernel call per grid."""
+    import jax
+    spec = make_stencil("box", 3, 1, seed=52)
+    xs = jnp.asarray(rng.normal(size=(2, 6, 9, 12)), jnp.float32)
+    got = jax.jit(jax.vmap(StencilEngine(spec, backend="pallas_sptc")._fn))(xs)
+    direct = StencilEngine(spec, backend="direct")
+    for i in range(xs.shape[0]):
+        np.testing.assert_allclose(np.asarray(got[i]), np.asarray(direct(xs[i])),
+                                   rtol=2e-5, atol=2e-5)

@@ -223,3 +223,27 @@ def test_engine_exposes_plan_ir():
     assert eng.plan_ir.stage_names() == (
         "row-decompose", "kernel-matrix", "strided-swap",
         "gather-schedule", "emit")
+
+
+def test_box3d_on_pallas_sptc_lowers_to_planes():
+    """A 3-D box on the fused SpTC kernel decomposes by plane columns:
+    one op along y per (z, x) offset; other backends, temporal blocking
+    and radius 2 keep the row decomposition."""
+    spec = make_stencil("box", 3, 1, seed=52)
+    plan = lower_spec(spec, "pallas_sptc")
+    dec = plan.decompose
+    assert dec.mode == "planes" and len(dec.ops) == 9
+    assert {op.axis for op in dec.ops} == {1}
+    assert sorted(op.lead for op in dec.ops) == [(a, c) for a in range(3)
+                                                 for c in range(3)]
+    for op in dec.ops:
+        a, c = op.lead
+        np.testing.assert_array_equal(dec.kernels[op.operand],
+                                      spec.weights[a, :, c])
+    assert plan.sparsify.shared_pattern
+    assert "row-decompose[planes x9]" in plan.describe()
+    assert lower_spec(spec, "sptc").decompose.mode == "rows"
+    assert lower_spec(spec, "pallas_sptc",
+                      temporal_steps=2).decompose.mode == "rows"
+    assert lower_spec(make_stencil("box", 3, 2, seed=52),
+                      "pallas_sptc").decompose.mode == "rows"

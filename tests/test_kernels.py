@@ -342,3 +342,42 @@ def test_conv1d_causality(rng):
     y2 = conv1d_causal_ref(x2, w)
     np.testing.assert_array_equal(np.asarray(y1[:, :10]),
                                   np.asarray(y2[:, :10]))
+
+
+# ---------------------------------------------------------------------------
+# sptc_onehot3d — the planes kernel's row tiles
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("r", [1, 2, 3])
+@pytest.mark.parametrize("ny", [5, 9, 26, 128, 130, 133, 300, 484, 1026, 1032])
+def test_planes_geometry(r, ny):
+    """Tiles start on sublane tiles, fit the plane's block, and give every
+    interior row the whole window its taps read; B + 2r fits one MXU tile."""
+    from repro.kernels.common import MXU, round_up
+    from repro.kernels.sptc_spmm.kernel import _planes_geometry
+    if ny <= 2 * r:
+        return
+    B, rows, runs, shifts = _planes_geometry(r, ny)
+    by = round_up(ny, 8)
+    assert B % 8 == 0 and rows <= MXU and (B + 2 * r <= MXU or B == by)
+    covered = set()
+    for o0, s0, j, n in runs:
+        assert n == 1 or shifts[j] == 0
+        for k in range(n):
+            o, s = o0 + k * B, s0 + k * B
+            assert o % 8 == 0 and o + B <= by and 0 <= s and s + rows <= ny
+            assert o - s - r == shifts[j]
+            for p in range(max(o, r), min(o + B, ny - r)):
+                assert s <= p - r and p + r < s + rows
+                covered.add(p)
+    assert covered == set(range(r, ny - r))
+
+
+def test_planes_geometry_box_cell():
+    """Box-3D27P's 1026-row planes: 120-row tiles from 128-row windows, the
+    seven between the first and the last in one run."""
+    from repro.kernels.sptc_spmm.kernel import _planes_geometry
+    B, rows, runs, shifts = _planes_geometry(1, 1026)
+    assert (B, rows) == (120, 128)
+    assert runs == ((0, 0, 0, 1), (120, 119, 1, 7), (912, 898, 2, 1))
+    assert shifts == (-1, 0, 13)

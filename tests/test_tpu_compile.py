@@ -125,7 +125,8 @@ def _cell_trace(cell, topo):
                                  sharding=NamedSharding(mesh, P(*mesh.axis_names)))
     else:
         eng = StencilEngine(spec, backend=wl["backend"])
-        u = jax.ShapeDtypeStruct((n + 2 * spec.radius,) * 2, jnp.float32,
+        u = jax.ShapeDtypeStruct((n + 2 * spec.radius,) * len(wl["grid"]),
+                                 jnp.float32,
                                  sharding=SingleDeviceSharding(topo.devices[0]))
     return jax.jit(lambda v: eng.iterate(v, spc), donate_argnums=0).trace(u)
 
@@ -161,15 +162,22 @@ def test_box_cell_onehot_grid_fills_the_mxu(topo, tpu_compile):
         assert math.prod(grid) <= 25600 // 8, grid
 
 
+#: cells whose timed program does all its work on the grid in its kernels
+_KERNELS_ONLY = {"box-3d27p.iterate"}
+
+
 @pytest.mark.parametrize("cell,kernel", [
     ("box-2d49p.iterate", "sptc_onehot"),
     ("heat-2d.iterate", "sptc_star"),
     ("heat-2d.iterate-2x2", "sptc_star"),
+    ("box-3d27p.iterate", "sptc_onehot3d"),
 ])
 def test_cell_program_is_covered_by_stages(cell, kernel, topo, tpu_compile):
     """Every instruction of the timed program that works on the grid is a
     kernel, a collective or counts under a stage the program declares
-    (``bench/scopes.py``), and the fused kernel carries its path's name."""
+    (``bench/scopes.py``), and the fused kernel carries its path's name.
+    The 2-D cells' programs hold work besides their kernels; the 3-D
+    cell's program holds its kernels alone."""
     from bench import scopes, trace
     text = _cell_program(cell, topo)
     kernels, collectives = trace.classify_hlo([text])
@@ -184,5 +192,71 @@ def test_cell_program_is_covered_by_stages(cell, kernel, topo, tpu_compile):
     stray = [(i.name, i.opcode, i.op_name) for i in work
              if i.name not in kernels and i.name not in collectives
              and where[i.name] == scopes.UNSCOPED]
-    assert len(work) > len(kernels) and not stray, stray
+    if cell in _KERNELS_ONLY:
+        assert {i.name for i in work} == kernels
+    else:
+        assert len(work) > len(kernels)
+    assert not stray, stray
     assert {where[c] for c in collectives} <= {"halo.exchange"}
+
+
+#: the HBM a v5e chip's programs may use, as XLA reports it
+_V5E_HBM = 15.75 * 2**30
+
+
+def test_box3d_cell_fits_one_chip_with_one_kernel_a_step(topo, tpu_compile):
+    """Box-3D27P's timed program at 1024^3 f32: one ``sptc_onehot3d`` call
+    a step and no other Pallas call, no copy, pad or slice of the grid
+    anywhere in it, and within one v5e's HBM (arguments, temporaries and
+    outputs not aliased to arguments)."""
+    from repro.core.stencil import paper_suite
+    from bench import harness
+    wl = harness.load_workload_file("box-3d27p.iterate")
+    spc = int(wl["steps_per_call"])
+    traced = _cell_trace("box-3d27p.iterate", topo)
+    scans = [e for e in traced.jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+    assert len(scans) == 1 and scans[0].params["length"] == spc
+    body = list(_pallas_grids(scans[0].params["jaxpr"].jaxpr))
+    assert [name for name, _ in body] == ["sptc_onehot3d"]
+    assert [name for name, _ in _pallas_grids(traced.jaxpr.jaxpr)] == ["sptc_onehot3d"]
+    compiled = traced.lower().compile()
+    m = compiled.memory_analysis()
+    used = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+    assert used <= _V5E_HBM, used
+    spec = next(s for s in paper_suite() if s.name == "box-3d1r")
+    side = int(wl["grid"][0]) + 2 * spec.radius
+    grid = re.compile(rf"= f32\[{side},{side},{side}\]\S* (\S+)\(")
+    ops = {m.group(1) for m in grid.finditer(compiled.as_text())}
+    assert ops <= {"parameter", "custom-call", "get-tuple-element", "tuple",
+                   "while", "bitcast"}, ops
+
+
+def _widest_square_plane(r, n_ops):
+    """The widest ``w`` whose ``w x w`` planes the planes kernel takes."""
+    from repro.kernels.sptc_spmm.kernel import planes_fit
+    w = 3
+    while planes_fit((64, w + 1, w + 1), jnp.float32, r, n_ops):
+        w += 1
+    return w
+
+
+def test_box3d_planes_kernel_compiles_up_to_its_vmem_check(one_chip,
+                                                           tpu_compile):
+    """The planes kernel's VMEM check is sound at its edge: the widest
+    square planes it admits compile for a v5e as one ``sptc_onehot3d``
+    call a step.  Planes one row and lane wider run the row ops one by
+    one (``sptc_onehot``), and so do 2048^2 planes, which the kernel
+    cannot hold; both compile as well."""
+    spec = make_stencil("box", 3, 1, seed=52)
+    eng = StencilEngine(spec, backend="pallas_sptc")
+    w = _widest_square_plane(1, 9)
+    for side, kernel in [(w, "sptc_onehot3d"), (w + 1, "sptc_onehot"),
+                         (2050, "sptc_onehot")]:
+        # deep enough that the compiler keeps the state out of VMEM
+        u = jax.ShapeDtypeStruct((64, side, side), jnp.float32,
+                                 sharding=one_chip)
+        traced = jax.jit(lambda v: eng.iterate(v, 2),
+                         donate_argnums=0).trace(u)
+        assert {n for n, _ in _pallas_grids(traced.jaxpr.jaxpr)} == {kernel}
+        assert "tpu_custom_call" in traced.lower().compile().as_text()

@@ -379,7 +379,7 @@ def test_save_conflicts_prefer_memory(tmp_path):
 def test_tuned_matches_direct_over_paper_suite(mode, rng):
     cache = PlanCache()
     for spec in paper_suite():
-        dims = {1: (131,), 2: (24, 27)}[spec.ndim]
+        dims = {1: (131,), 2: (24, 27), 3: (9, 10, 11)}[spec.ndim]
         x = _x(spec, dims, rng)
         got = tuned_apply(spec, x, cache=cache, mode=mode)
         want = apply_stencil(spec, x, backend="direct")
