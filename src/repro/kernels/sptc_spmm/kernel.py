@@ -44,10 +44,16 @@ contraction tile (120 rows for L = 4, 6, 8 in f32; 112 for L = 8 in
 bf16), clamped to the output's extent, so one ``(B, rows) x (rows, bn)``
 dot serves ~120 streamed rows per latched weight chunk and reads each
 input row ~1.07 times; ``bn`` splits the lane extent into equal
-128-multiples of at most 2048.  The star path keeps its 8-row tiles (the
-least multiple of ``L`` filling whole sublane tiles: 8 rows for L = 4, 24
-for L = 6) and 512-lane blocks for now.  A leading batch grid axis serves
-``vmap`` (``common.fold_vmap``).
+128-multiples of at most 2048.  The star path has no MXU tile to fill:
+its ``B`` is the largest such multiple whose step (window slots, output
+blocks, the f32 accumulator and shifted window slices) ``star_vmem_bytes``
+counts within ``_STAR_VMEM_BUDGET``, clamped to the output the same way,
+and the call sets its VMEM limit from that count: 384 rows of 2048 lanes
+at 10240², 135 grid steps a call.  Its last step's window holds only the
+rows its outputs read, so the wrapper pads no more rows than steps of
+``lcm(L, sublane tile)`` rows would, and its output is exactly
+``(n_out, C)``, the last blocks' writes clipped, so nothing is cropped.
+A leading batch grid axis serves ``vmap`` (``common.fold_vmap``).
 
 Both ``*_call`` entry points resolve ``interpret=None`` through
 ``common.default_interpret()`` at call time: compiled Mosaic on a real
@@ -132,42 +138,58 @@ def sptc_spmm_call(values, meta, x, *, block_n: int = 512,
 
 def _fused_kernel(x_hbm, vals_ref, meta_ref, y_ref, scratch, sem,
                   *onehot_scratch,
-                  tiles: int, L: int, rows: int, bn: int, star_fast: bool,
-                  compute):
+                  tiles: int, L: int, rows: int, last: int, bn: int,
+                  star_fast: bool, compute):
     """One grid step computes ``B`` output rows — ``B // L`` stacked row
     tiles of ``L`` — of batch item ``b`` from a ``rows``-row window
     starting at row ``t·B``.
 
     ``B`` and ``rows`` are multiples of the sublane tile, so the output
     block, the window DMA and its row offset are all (8, 128)-aligned.
-    On the star path ``vals_ref`` / ``meta_ref`` hold the operand's L rows
-    repeated per stacked tile (``B`` rows); the one-hot path takes the L
-    rows once and stacks them as it decompresses into its one VMEM scratch
-    buffer, the (B, rows) operand ``w_ref``.
+    The last step's window holds only the ``last`` rows its outputs read;
+    the window rows below them are left over from an earlier step and
+    reach only output rows past ``n_out``, which are never stored.
+    On the star path ``vals_ref`` holds the operand's L rows repeated per
+    stacked tile (``B`` rows) and ``meta_ref`` is not read; the one-hot
+    path takes the L rows once and stacks them as it decompresses into its
+    one VMEM scratch buffer, the (B, rows) operand ``w_ref``.
     """
     b = pl.program_id(0)
     j = pl.program_id(1)
     t = pl.program_id(2)
     B, kh = y_ref.shape[0], vals_ref.shape[1]
 
-    def dma(slot, tt):
+    def dma(slot, tt, n=rows):
+        dst = scratch.at[slot] if n == rows else scratch.at[slot, pl.ds(0, n)]
         return pltpu.make_async_copy(
-            x_hbm.at[b, pl.ds(pl.multiple_of(tt * B, B), rows),
+            x_hbm.at[b, pl.ds(pl.multiple_of(tt * B, B), n),
                      pl.ds(pl.multiple_of(j * bn, bn), bn)],
-            scratch.at[slot], sem.at[slot])
+            dst, sem.at[slot])
+
+    def each(slot, tt, op):
+        """``op`` on the DMA of tile ``tt``'s window into ``slot``."""
+        if last == rows or (isinstance(tt, int) and tt < tiles - 1):
+            op(dma(slot, tt))
+        elif tiles == 1:
+            op(dma(slot, tt, last))
+        else:
+            pl.when(tt == tiles - 1)(lambda: op(dma(slot, tt, last)))
+            pl.when(tt < tiles - 1)(lambda: op(dma(slot, tt)))
+
+    start, wait = (lambda d: d.start()), (lambda d: d.wait())
 
     # cross-grid-step double buffering: scratch persists across the
     # sequential row-tile axis (grid iterates it fastest), so tile t+1's
     # window streams from HBM while tile t computes.
     @pl.when(t == 0)
     def _():
-        dma(0, 0).start()
+        each(0, 0, start)
 
     @pl.when(t + 1 < tiles)
     def _():
-        dma((t + 1) % 2, t + 1).start()
+        each((t + 1) % 2, t + 1, start)
 
-    dma(t % 2, t).wait()
+    each(t % 2, t, wait)
     win = scratch[t % 2]                     # (rows, bn)
     vals = vals_ref[:]                       # (B, K/2)
     if compute is not None:
@@ -240,8 +262,8 @@ def _decompress(vals, words, B: int, L: int, rows: int, shift: int = 0):
     return w_dense
 
 
-#: widest lane block of a one-hot grid step
-_ONEHOT_MAX_BN = 2048
+#: widest lane block of a fused grid step
+_MAX_BN = 2048
 
 
 def _sublanes(dtype) -> int:
@@ -256,24 +278,30 @@ def _fused_geometry(L: int, n_out: int, c: int, dtype, compute,
     ``B`` is a multiple of ``lcm(L, sub)``, ``sub`` the sublane tile of the
     input ``dtype`` (and, on the one-hot path, of the ``compute`` dtype);
     the window covers the ``B + L`` rows those outputs read, rounded up to
-    ``sub``.  The star path takes the least such ``B`` and 512 lanes.  The
-    one-hot path takes the largest ``B`` whose window fits one MXU
-    contraction tile, then the least ``B`` that covers ``n_out`` in as many
-    steps, so a short output gets one step of ``round_up(n_out, lcm)``
-    rows; ``bn`` splits the lane extent into equal 128-multiples of at most
-    ``_ONEHOT_MAX_BN``.
+    ``sub``, and ``bn`` splits the lane extent into equal 128-multiples of
+    at most ``_MAX_BN``.  The one-hot path takes the largest ``B`` whose
+    window fits one MXU contraction tile; the star path, which has no MXU
+    tile to fill, the largest whose step ``star_vmem_bytes`` counts within
+    ``_STAR_VMEM_BUDGET``.  Both then take the least ``B`` that covers
+    ``n_out`` in as many steps, so a short output gets one step of
+    ``round_up(n_out, lcm)`` rows, and a narrow one tall steps over 128
+    lanes.
     """
     lanes = round_up(c, common.LANES)
-    if star_fast:
-        sub = _sublanes(dtype)
-        B = L * sub // math.gcd(L, sub)
-        return B, round_up(B + L, sub), min(512, lanes)
-    sub = max(_sublanes(dtype), _sublanes(compute or dtype))
-    m = L * sub // math.gcd(L, sub)
-    B = max(m, (common.MXU - L) // m * m)
-    B = round_up(-(-n_out // -(-n_out // B)), m)
-    nj = -(-lanes // _ONEHOT_MAX_BN)
+    nj = -(-lanes // _MAX_BN)
     bn = round_up(-(-lanes // nj), common.LANES)
+    sub = _sublanes(dtype)
+    if not star_fast:
+        sub = max(sub, _sublanes(compute or dtype))
+    m = L * sub // math.gcd(L, sub)
+    if star_fast:
+        B = m
+        while star_vmem_bytes(B + m, round_up(B + m + L, sub), bn, L, dtype,
+                              compute) <= _STAR_VMEM_BUDGET:
+            B += m
+    else:
+        B = max(m, (common.MXU - L) // m * m)
+    B = round_up(-(-n_out // -(-n_out // B)), m)
     return B, round_up(B + L, sub), bn
 
 
@@ -285,17 +313,26 @@ def _sptc_fused_jit(x3d, values, meta_bits, *, n_out: int, L: int,
     compute = jnp.dtype(compute_dtype) if compute_dtype else None
     B, rows, bn = _fused_geometry(L, n_out, c, x3d.dtype, compute, star_fast)
     tiles = -(-n_out // B)
-    need = (tiles - 1) * B + rows
     c_pad = round_up(c, bn)
+    last, out, params = rows, (nb, tiles * B, c_pad), None
+    if star_fast:
+        # the last window reads only the rows its outputs need, and the
+        # output is exactly (n_out, c): the last blocks' writes are clipped
+        last = min(rows, round_up(n_out - (tiles - 1) * B + L,
+                                  _sublanes(x3d.dtype)))
+        out = (nb, n_out, c)
+        params = pltpu.CompilerParams(vmem_limit_bytes=star_vmem_bytes(
+            B, rows, bn, L, x3d.dtype, compute))
+    need = (tiles - 1) * B + last
     with jax.named_scope(KERNEL_PREP):
         if need > rows_in or c_pad != c:
             x3d = jnp.pad(x3d, ((0, 0), (0, max(0, need - rows_in)),
                                 (0, c_pad - c)))
         if star_fast:
             values = jnp.tile(values, (B // L, 1))
-            meta_bits = jnp.tile(meta_bits, (B // L, 1))
     kern = functools.partial(_fused_kernel, tiles=tiles, L=L, rows=rows,
-                             bn=bn, star_fast=star_fast, compute=compute)
+                             last=last, bn=bn, star_fast=star_fast,
+                             compute=compute)
     scratch = [pltpu.VMEM((2, rows, bn), x3d.dtype),
                pltpu.SemaphoreType.DMA((2,))]
     if not star_fast:
@@ -310,8 +347,9 @@ def _sptc_fused_jit(x3d, values, meta_bits, *, n_out: int, L: int,
             pl.BlockSpec(meta_bits.shape, lambda b, j, t: (0, 0)),
         ],
         out_specs=pl.BlockSpec((None, B, bn), lambda b, j, t: (b, t, j)),
-        out_shape=jax.ShapeDtypeStruct((nb, tiles * B, c_pad), x3d.dtype),
+        out_shape=jax.ShapeDtypeStruct(out, x3d.dtype),
         scratch_shapes=scratch,
+        compiler_params=params,
         interpret=interpret,
     )(x3d, values, meta_bits)
     with jax.named_scope(KERNEL_PREP):
@@ -341,15 +379,70 @@ def sptc_fused_call(values, meta_bits, x2d, *, n_out: int, L: int,
 
 
 # ---------------------------------------------------------------------------
-# 3-D planes: every row op of a 3-D box step in one program
+# VMEM: what a grid step of the star path and of the planes kernel holds
 # ---------------------------------------------------------------------------
 
 #: most VMEM the planes kernel may ask for (v5e holds 128 MiB)
 _PLANES_VMEM_CAP = 100 << 20
+#: most VMEM a star grid step may count (``star_vmem_bytes``): past about
+#: 256 rows of 2048 lanes a call runs within 1% of its fastest
+_STAR_VMEM_BUDGET = 32 << 20
 #: room beside the counted buffers and tile values, for Mosaic's own
 #: scratch and the rounding of its allocations
 _MOSAIC_SCRATCH = 4 << 20
 
+
+def star_vmem_bytes(B: int, rows: int, bn: int, kh: int, dtype,
+                    compute=None) -> int:
+    """Scoped VMEM a star grid step takes: ``B`` output rows from a
+    ``rows``-row window, ``bn`` lanes, ``kh`` taps.
+
+    The two window slots and the two output blocks in ``dtype``; the f32
+    accumulator and the ``kh`` shifted window slices the FMA loop
+    materialises (and the window cast to f32 where the input or the
+    ``compute`` dtype is not f32); the (B, kh) values, two blocks padded
+    to 128 lanes; and ``_MOSAIC_SCRATCH``.  In compiles for a described
+    v5e that bounds the kernel's need from 8 x 128 to 10240 x 128 and
+    512 x 2048 steps, f32 and bf16.
+    """
+    item = jnp.dtype(dtype).itemsize
+    f32 = (kh + 1) * B
+    if item != 4 or compute is not None:
+        f32 += rows
+    return ((2 * (rows + B) * item + f32 * 4) * bn
+            + 2 * B * common.LANES * 4 + _MOSAIC_SCRATCH)
+
+
+def planes_vmem_bytes(shape, dtype, r: int, n_ops: int) -> int:
+    """Scoped VMEM the planes kernel takes on a ``(Z, Y, X)`` state.
+
+    Its double-buffered input and output planes, the decompressed bands,
+    and the f32 values of each row-tile body, which Mosaic keeps in VMEM
+    once per run of tiles (each run's body is its own code): ``n_ops +
+    2(2r + 1)`` values of ``B`` rows and the ``2r + 1`` windows, each as
+    wide as the plane.  In compiles for a v5e that bounds the kernel's
+    need on planes from 10 x 32768 and 18 x 32768 to 4096 x 258 and
+    1026 x 2050; ``_MOSAIC_SCRATCH`` is the room above it.
+    """
+    ny, nx = shape[-2:]
+    by, bx = round_up(ny, common.SUBLANES), round_up(nx, common.LANES)
+    B, rows, runs, shifts = _planes_geometry(r, ny)
+    n_in = 2 * r + 1
+    item = jnp.dtype(dtype).itemsize
+    return (2 * (n_in + 1) * by * bx * item
+            + len(shifts) * n_ops * B * rows * item
+            + len(runs) * ((n_ops + 2 * n_in) * B + n_in * rows) * bx * 4
+            + _MOSAIC_SCRATCH)
+
+
+def planes_fit(shape, dtype, r: int, n_ops: int) -> bool:
+    """Whether the planes kernel's VMEM holds a ``(Z, Y, X)`` state."""
+    return planes_vmem_bytes(shape, dtype, r, n_ops) <= _PLANES_VMEM_CAP
+
+
+# ---------------------------------------------------------------------------
+# 3-D planes: every row op of a 3-D box step in one program
+# ---------------------------------------------------------------------------
 
 def _planes_geometry(r: int, ny: int):
     """(output rows per tile ``B``, window rows, tile runs, band shifts).
@@ -469,33 +562,6 @@ def _planes_kernel(*refs, leads, r: int, L: int, B: int, rows: int, runs,
 
 def _plane_index(z, *, d: int, nz: int):
     return jnp.clip(z + d, 0, nz - 1), 0, 0
-
-
-def planes_vmem_bytes(shape, dtype, r: int, n_ops: int) -> int:
-    """Scoped VMEM the planes kernel takes on a ``(Z, Y, X)`` state.
-
-    Its double-buffered input and output planes, the decompressed bands,
-    and the f32 values of each row-tile body, which Mosaic keeps in VMEM
-    once per run of tiles (each run's body is its own code): ``n_ops +
-    2(2r + 1)`` values of ``B`` rows and the ``2r + 1`` windows, each as
-    wide as the plane.  In compiles for a v5e that bounds the kernel's
-    need on planes from 10 x 32768 and 18 x 32768 to 4096 x 258 and
-    1026 x 2050; ``_MOSAIC_SCRATCH`` is the room above it.
-    """
-    ny, nx = shape[-2:]
-    by, bx = round_up(ny, common.SUBLANES), round_up(nx, common.LANES)
-    B, rows, runs, shifts = _planes_geometry(r, ny)
-    n_in = 2 * r + 1
-    item = jnp.dtype(dtype).itemsize
-    return (2 * (n_in + 1) * by * bx * item
-            + len(shifts) * n_ops * B * rows * item
-            + len(runs) * ((n_ops + 2 * n_in) * B + n_in * rows) * bx * 4
-            + _MOSAIC_SCRATCH)
-
-
-def planes_fit(shape, dtype, r: int, n_ops: int) -> bool:
-    """Whether the planes kernel's VMEM holds a ``(Z, Y, X)`` state."""
-    return planes_vmem_bytes(shape, dtype, r, n_ops) <= _PLANES_VMEM_CAP
 
 
 @functools.partial(jax.jit, static_argnames=("leads", "L", "r", "interpret"))

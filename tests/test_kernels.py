@@ -113,11 +113,33 @@ def test_sptc_fused_rejects_non_swap_perm(rng):
         sptc_spmm_fused(sk.sparse, np.arange(2 * sk.L), x, n_out=8, L=sk.L)
 
 
-def _star_geometry_8row(L, sub):
-    """The fused kernel's (B, rows) before the one-hot path took MXU-sized
-    steps; the star path keeps it."""
-    B = L * sub // math.gcd(L, sub)
-    return B, -(-(B + L) // sub) * sub
+def _pallas_eqns(jaxpr):
+    """Every ``pallas_call`` equation in ``jaxpr`` and its nested jaxprs."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+            continue
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _pallas_eqns(sub)
+
+
+def _star_call(L, n_out, c, dtype):
+    """(input rows the kernel reads after the wrapper's pad, grid, VMEM
+    limit, output shape) of the star path's one ``pallas_call`` on an
+    (n_out, c) input: traced, not run."""
+    import jax
+    from repro.kernels.sptc_spmm.kernel import sptc_fused_call
+    call = lambda x: sptc_fused_call(
+        jnp.zeros((L, L), dtype), jnp.zeros((L, 1), jnp.uint32), x,
+        n_out=n_out, L=L, star_fast=True, interpret=True)
+    jaxpr = jax.make_jaxpr(call)(jax.ShapeDtypeStruct((n_out, c), dtype))
+    (eqn,) = _pallas_eqns(jaxpr.jaxpr)
+    limit = eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+    return (eqn.invars[0].aval.shape[1], eqn.params["grid_mapping"].grid,
+            limit, eqn.outvars[0].aval.shape)
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
@@ -125,10 +147,13 @@ def _star_geometry_8row(L, sub):
 @pytest.mark.parametrize("n_out,c", [("small", 64), (10240, 10240),
                                      (1000, 2200)])
 def test_sptc_fused_geometry(L, dtype, n_out, c):
-    from repro.kernels.sptc_spmm.kernel import _fused_geometry
+    from repro.kernels.sptc_spmm.kernel import (_STAR_VMEM_BUDGET,
+                                                _fused_geometry,
+                                                star_vmem_bytes)
     n_out = 3 * L + 2 if n_out == "small" else n_out
     sub = 32 // jnp.dtype(dtype).itemsize
     m = L * sub // math.gcd(L, sub)
+    lane_blocks = lambda bn: -(-c // bn)
     B, rows, bn = _fused_geometry(L, n_out, c, dtype, None, star_fast=False)
     assert B % m == 0 and rows % sub == 0
     assert B + L <= rows <= 128                   # one MXU contraction tile
@@ -138,11 +163,20 @@ def test_sptc_fused_geometry(L, dtype, n_out, c):
         assert B == -(-n_out // m) * m
     else:                                         # the widest B's steps
         assert tiles == -(-n_out // ((128 - L) // m * m))
-    lane_blocks = -(-c // bn)                     # equal 128-lane multiples
-    assert bn % 128 == 0 and bn <= 2048 and lane_blocks * bn - c < 128 * lane_blocks
+    nj = lane_blocks(bn)                          # equal 128-lane multiples
+    assert bn % 128 == 0 and bn <= 2048 and nj * bn - c < 128 * nj
+    # the star path: steps as large as the VMEM it counts allows
     sB, srows, sbn = _fused_geometry(L, n_out, c, dtype, None, star_fast=True)
-    assert (sB, srows) == _star_geometry_8row(L, sub)
-    assert sbn == min(512, -(-c // 128) * 128)
+    assert sB % m == 0 and srows % sub == 0 and sB + L <= srows
+    stiles = -(-n_out // sB)
+    assert (stiles - 1) * sB < n_out <= stiles * sB
+    nj = lane_blocks(sbn)
+    assert sbn % 128 == 0 and sbn <= 2048 and nj * sbn - c < 128 * nj
+    need, grid, limit, _ = _star_call(L, n_out, c, dtype)
+    assert grid == (1, nj, stiles)
+    assert star_vmem_bytes(sB, srows, sbn, L, dtype) <= limit <= _STAR_VMEM_BUDGET
+    # no more rows padded than steps of lcm(L, sub) rows each took
+    assert need <= (-(-n_out // m) - 1) * m + -(-(m + L) // sub) * sub
 
 
 def test_sptc_fused_geometry_box_cell():
@@ -155,24 +189,53 @@ def test_sptc_fused_geometry_box_cell():
                            star_fast=False)[:2] == (112, 128)
 
 
+@pytest.mark.parametrize("n_out,c,geometry,grid,need", [
+    (10240, 10240, (384, 392, 2048), (1, 5, 27), 10248),    # heat-2d.iterate
+    (20478, 20478, (384, 392, 2048), (1, 10, 54), 20488),   # 2x2 interior
+    (1, 20480, (8, 16, 2048), (1, 10, 1), 8),               # 2x2 rims
+    (20480, 1, (5120, 5128, 128), (1, 1, 4), 20488),
+])
+def test_sptc_fused_geometry_heat_cells(n_out, c, geometry, grid, need):
+    """Heat-2D's star row ops (L = 4, f32): at 10240² 384-row steps over
+    2048 lanes, 27 × 5 grid steps where 8-row, 512-lane steps took
+    1280 × 20, with the same 10248 input rows; the 2×2's interior and rim
+    slabs likewise, a short output in one step and a one-lane extent in
+    tall steps over 128 lanes.  The kernel writes the output at its own
+    size, so nothing is cropped after the call."""
+    from repro.kernels.sptc_spmm.kernel import _fused_geometry
+    assert _fused_geometry(4, n_out, c, jnp.float32, None,
+                           star_fast=True) == geometry
+    got_need, got_grid, _, out = _star_call(4, n_out, c, jnp.float32)
+    assert (got_need, got_grid, out) == (need, grid, (1, n_out, c))
+
+
+@pytest.mark.parametrize("star_fast,n_out", [(False, 397), (True, 1700)])
 @pytest.mark.parametrize("r,batch", [(1, None), (2, None), (3, None),
                                      (2, 2)])
-def test_sptc_fused_onehot_row_blocks_vs_direct(r, batch, rng):
-    """Several MXU-sized row blocks with a ragged tail, and a lane extent
-    that is no multiple of the lane block; ``batch`` runs through ``vmap``
-    (the kernel's batch grid axis)."""
+def test_sptc_fused_onehot_row_blocks_vs_direct(r, batch, star_fast, n_out,
+                                                rng):
+    """Several row blocks with a ragged tail, and a lane extent that is no
+    multiple of the lane block, on either path; the star path's last step
+    reads a short window.  ``batch`` runs through ``vmap`` (the kernel's
+    batch grid axis)."""
     import jax
     from repro.kernels.sptc_spmm.kernel import _fused_geometry
     from repro.kernels.sptc_spmm.ops import sptc_spmm_fused
     w = rng.normal(size=2 * r + 1)
     sk = sparsify_stencil_kernel(w)
-    n_out, c = 397, 2200
-    B, _, bn = _fused_geometry(sk.L, n_out, c, jnp.float32, None, False)
-    assert -(-n_out // B) >= 3 and n_out % B and c % bn and c > bn
+    c = 2200
+    B, rows, bn = _fused_geometry(sk.L, n_out, c, jnp.float32, None,
+                                  star_fast)
+    tiles = -(-n_out // B)
+    assert tiles >= 3 and n_out % B and c % bn and c > bn
+    if star_fast:
+        assert _star_call(sk.L, n_out, c, jnp.float32)[0] < (
+            (tiles - 1) * B + rows)
     lead = () if batch is None else (batch,)
     x = rng.normal(size=lead + (n_out + 2 * r, c)).astype(np.float32)
     call = lambda v: sptc_spmm_fused(sk.sparse, sk.perm, v, n_out=n_out,
-                                     L=sk.L, star_fast=False, interpret=True)
+                                     L=sk.L, star_fast=star_fast,
+                                     interpret=True)
     got = np.asarray(call(jnp.asarray(x)) if batch is None
                      else jax.vmap(call)(jnp.asarray(x)))
     want = np.stack([_direct_1d(w, xb, n_out) for xb in x.reshape(

@@ -136,30 +136,54 @@ def _cell_program(cell, topo):
     return _cell_trace(cell, topo).lower().compile().as_text()
 
 
-def _pallas_grids(jaxpr):
-    """(kernel name, grid) of every ``pallas_call`` in ``jaxpr``, nested
-    jaxprs (jit, scan, custom_vmap) included."""
+def _pallas_eqns(jaxpr):
+    """Every ``pallas_call`` equation in ``jaxpr`` and its nested jaxprs
+    (jit, scan, custom_vmap)."""
     from jax.extend.core import ClosedJaxpr, Jaxpr
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            yield eqn.params["name"], eqn.params["grid_mapping"].grid
+            yield eqn
             continue
         for v in eqn.params.values():
             for sub in v if isinstance(v, (list, tuple)) else (v,):
                 if isinstance(sub, ClosedJaxpr):
                     sub = sub.jaxpr
                 if isinstance(sub, Jaxpr):
-                    yield from _pallas_grids(sub)
+                    yield from _pallas_eqns(sub)
 
 
-def test_box_cell_onehot_grid_fills_the_mxu(topo, tpu_compile):
-    """The box cell's one-hot kernels take MXU-sized grid steps: at most
-    1/8 of the 25,600 steps a call that 8-row, 512-lane steps took."""
-    jaxpr = _cell_trace("box-2d49p.iterate", topo).jaxpr.jaxpr
-    grids = list(_pallas_grids(jaxpr))
-    assert len(grids) == 7 and {n for n, _ in grids} == {"sptc_onehot"}
-    for _, grid in grids:
-        assert math.prod(grid) <= 25600 // 8, grid
+def _pallas_grids(jaxpr):
+    """(kernel name, grid) of every ``pallas_call`` in ``jaxpr``."""
+    for eqn in _pallas_eqns(jaxpr):
+        yield eqn.params["name"], eqn.params["grid_mapping"].grid
+
+
+@pytest.mark.parametrize("cell,kernel,calls,steps", [
+    ("box-2d49p.iterate", "sptc_onehot", 7, 25600 // 8),
+    ("heat-2d.iterate", "sptc_star", 2, 25600 // 32),
+    ("heat-2d.iterate-2x2", "sptc_star", 10, 102400 // 32),
+])
+def test_box_cell_onehot_grid_fills_the_mxu(cell, kernel, calls, steps, topo,
+                                            tpu_compile):
+    """The cells' fused kernels take wide grid steps.  The box's one-hot
+    kernels take MXU-sized ones: at most 1/8 of the 25,600 steps a call
+    that 8-row, 512-lane steps took at 10240².  The heat cells' star
+    kernels take steps sized by the VMEM they count: at most 1/32 of those
+    steps, and of the 102,400 such steps at the 2×2's 20480² blocks; each
+    star call sets its VMEM limit, and the heat programs compile for a
+    v5e within it."""
+    from repro.kernels.sptc_spmm.kernel import _STAR_VMEM_BUDGET
+    traced = _cell_trace(cell, topo)
+    eqns = list(_pallas_eqns(traced.jaxpr.jaxpr))
+    assert len(eqns) == calls and {e.params["name"] for e in eqns} == {kernel}
+    for eqn in eqns:
+        grid = eqn.params["grid_mapping"].grid
+        assert math.prod(grid) <= steps, grid
+    if kernel == "sptc_star":
+        for eqn in eqns:
+            params = eqn.params["compiler_params"]["mosaic_tpu"]
+            assert 0 < params.vmem_limit_bytes <= _STAR_VMEM_BUDGET
+        assert "tpu_custom_call" in traced.lower().compile().as_text()
 
 
 #: cells whose timed program does all its work on the grid in its kernels
