@@ -9,7 +9,7 @@ interpret-mode wall clock recorded only for provenance.  Records the
 results as a **versioned JSON artifact** (``BENCH_kernels.json``)
 mirroring ``serving_bench.py``'s ``BENCH_serving.json``: per-radius
 dense-GEMM vs compressed 2:4 SpMM time and useful-MAC throughput, the
-fused pallas_sptc v2 kernel sweep (general / star-fast / bf16 paths vs
+fused pallas_sptc kernel sweep (general / star-fast / bf16 paths vs
 the direct oracle, registry × radius/L), plus the end-to-end
 tuned-vs-default engine comparison per stencil.
 
@@ -76,7 +76,7 @@ def spmm_sweep(radii, n, iters, seed=0):
 
 
 def fused_kernel_sweep(radii, n, seed=2):
-    """Fused pallas_sptc v2 vs the direct oracle.
+    """Fused pallas_sptc kernel vs the direct oracle.
 
     All three kernel paths (general one-hot, star-fast banded, bf16
     compute) run in interpret mode and are checked allclose against the
@@ -106,8 +106,8 @@ def fused_kernel_sweep(radii, n, seed=2):
             return t, err
 
         t_gen, err_gen = run_path(False)
-        t_star, err_star = run_path("auto")
-        _, err_bf16 = run_path("auto", "bfloat16")
+        t_star, err_star = run_path(True)
+        _, err_bf16 = run_path(True, "bfloat16")
         tol = 2e-4 * max(1.0, float(np.max(np.abs(want))))
         rows.append({
             "radius": r, "L": L, "n_out": n_out, "n": n,
@@ -123,7 +123,7 @@ def fused_kernel_sweep(radii, n, seed=2):
 
 
 def fused_engine_sweep(points, n, seed=3):
-    """Engine-level pallas_sptc (fused v2) vs the direct oracle, over the
+    """Engine-level pallas_sptc (fused kernel) vs the direct oracle, over the
     stencil registry (shape × ndim) × radius — each point reports the
     plan's L and the max abs error."""
     from repro.core.engine import StencilEngine
@@ -237,7 +237,7 @@ def main(argv=None):
               f"{row['sptc_us']},{row['dense_gmacs']},{row['sptc_gmacs']}")
     print("# sptc executes K/2 — per-useful-MAC throughput is the metric")
     print()
-    print("# fused pallas_sptc v2 (interpret mode) vs direct oracle")
+    print("# fused pallas_sptc kernel (interpret mode) vs direct oracle")
     print("radius,L,general_ok,star_fast_ok,bf16_ok,interp_cpu_us")
     for row in payload["fused_kernel"]:
         print(f"{row['radius']},{row['L']},{row['general_ok']},"

@@ -6,7 +6,7 @@ from repro.core.sparsify import (Sparse24, SparseStencilKernel, encode_24,
                                  decode_24, is_24_sparse, strided_swap_perm,
                                  sparsify_matrices, sparsify_stencil_kernel)
 from repro.core.ir import LoweredPlan
-from repro.core.engine import StencilEngine, apply_stencil, apply_1d
+from repro.core.engine import StencilEngine, apply_stencil
 from repro.core import analysis, sptc
 
 __all__ = [
@@ -14,6 +14,6 @@ __all__ = [
     "default_l", "decompose_rows", "lower_spec", "Sparse24",
     "SparseStencilKernel", "encode_24", "decode_24", "is_24_sparse",
     "strided_swap_perm", "sparsify_matrices", "sparsify_stencil_kernel",
-    "LoweredPlan", "StencilEngine", "apply_stencil", "apply_1d",
+    "LoweredPlan", "StencilEngine", "apply_stencil",
     "analysis", "sptc",
 ]

@@ -13,7 +13,11 @@ Backends (all mathematically equivalent; cross-checked in tests):
               banded (L, 2L) matrix times 2L-row input windows.
   sptc        simulated Sparse Tensor Core execution: strided-swap permuted
               + 2:4-compressed kernel, row-swapped inputs (paper §3.2.2/§3.3).
-  pallas_*    Pallas TPU kernels (see repro.kernels), same math.
+  pallas_*    Pallas TPU kernels (see repro.kernels), same math.  A
+              ``pallas_sptc`` row op runs the star kernel (``sptc_star``)
+              in a ``single`` or ``star-axis`` plan and the one-hot kernel
+              (``sptc_onehot``) otherwise: the plan's mode decides, in
+              ``_apply_op`` alone.
 
 Two workload classes ride on IR-level attributes:
   * variable coefficients (``coefficients=`` on the engine): per-output-point
@@ -42,11 +46,11 @@ import numpy as np
 from repro.core import scopes
 from repro.core.ir import (BACKENDS, LoweredPlan, RowOp,
                            SegmentGatherSchedule)
-from repro.core.sparsify import Sparse24, decode_24, sparsify_stencil_kernel
+from repro.core.sparsify import Sparse24, decode_24
 from repro.core.stencil import StencilSpec
-from repro.core.transform import default_l, kernel_matrix, lower_spec
+from repro.core.transform import lower_spec
 
-__all__ = ["BACKENDS", "StencilEngine", "apply_stencil", "apply_1d"]
+__all__ = ["BACKENDS", "StencilEngine", "apply_stencil"]
 
 ApplyFn = Callable[[jnp.ndarray], jnp.ndarray]
 
@@ -58,7 +62,7 @@ _F32_DOT = jax.lax.Precision.HIGHEST
 # ---------------------------------------------------------------------------
 # 1-D application primitives (stencil axis leading, free axis trailing).
 # Each reads its tables as arguments — the interpreter feeds them from the
-# plan; `apply_1d` below builds them ad hoc for the standalone utility path.
+# plan.
 # ---------------------------------------------------------------------------
 
 def _windows(x2d: jnp.ndarray, n_out: int, L: int,
@@ -74,13 +78,11 @@ def _windows(x2d: jnp.ndarray, n_out: int, L: int,
     permutation into the gather's load addresses (paper §3.3: the input row
     swap is zero-cost — it must not lower to a separate permute/gather op).
     """
-    ntiles = -(-n_out // L)
-    need = (ntiles + 1) * L
+    x2d, ntiles = _pad_tiles(x2d, n_out, L)
     within = np.arange(2 * L) if order is None else np.asarray(order)
+    idx = (np.arange(ntiles) * L)[:, None] + within[None, :]
     with jax.named_scope(scopes.KERNEL_PREP):
-        x2d = jnp.pad(x2d, ((0, max(0, need - x2d.shape[0])), (0, 0)))
-        idx = (jnp.arange(ntiles) * L)[:, None] + jnp.asarray(within)[None, :]
-        return x2d[idx], ntiles
+        return x2d[jnp.asarray(idx)], ntiles
 
 
 def _pad_tiles(x2d: jnp.ndarray, n_out: int, L: int
@@ -146,13 +148,14 @@ def _op_pallas_mxu(K: np.ndarray, x2d: jnp.ndarray, n_out: int,
 
 def _op_pallas_sptc(operand: Sparse24, perm: np.ndarray, x2d: jnp.ndarray,
                     n_out: int, L: int, star_fast: bool) -> jnp.ndarray:
-    """Fused v2: ONE Pallas program — window DMA, in-kernel swap+segment
-    gather (from the packed meta_bits), MXU matmul.  Nothing is windowed,
-    permuted, or gathered outside the kernel (§3.3 zero runtime overhead;
-    certified by ``repro.vet``'s pallas-fused analyzer)."""
+    """ONE fused Pallas program: window DMA, then the star kernel's
+    banded FMAs (``star_fast``) or the one-hot kernel's in-kernel
+    swap+segment gather and MXU matmul.  Nothing is windowed, permuted,
+    or gathered outside the kernel (§3.3 zero runtime overhead; certified
+    by ``repro.vet``'s pallas-fused analyzer)."""
     from repro.kernels.sptc_spmm.ops import sptc_spmm_fused
     return sptc_spmm_fused(operand, perm, x2d, n_out=n_out, L=L,
-                           star_fast="auto" if star_fast else False)
+                           star_fast=star_fast)
 
 
 # ---------------------------------------------------------------------------
@@ -501,19 +504,17 @@ class StencilEngine:
     """Compiled applicator for one StencilSpec — lowers, then interprets."""
 
     def __init__(self, spec: StencilSpec, backend: str = "direct",
-                 L: Optional[int] = None, star_fast_path: bool = True,
-                 fuse_rows: bool = False, temporal_steps: int = 1,
+                 L: Optional[int] = None, fuse_rows: bool = False,
+                 temporal_steps: int = 1,
                  coefficients: Optional[np.ndarray] = None) -> None:
         if backend not in BACKENDS:
             raise ValueError(f"backend must be one of {BACKENDS}")
         self.plan_ir: LoweredPlan = lower_spec(
-            spec, backend=backend, L=L, star_fast_path=star_fast_path,
-            fuse_rows=fuse_rows, temporal_steps=temporal_steps,
-            coefficients=coefficients)
+            spec, backend=backend, L=L, fuse_rows=fuse_rows,
+            temporal_steps=temporal_steps, coefficients=coefficients)
         self.spec = spec
         self.backend = backend
         self.L = self.plan_ir.L
-        self.star_fast_path = star_fast_path and spec.shape == "star"
         # §Perf D: one window-gather + one stacked GEMM for all kernel rows
         self.fuse_rows = fuse_rows
         self.temporal_steps = temporal_steps
@@ -574,36 +575,3 @@ def apply_stencil(spec: StencilSpec, x: jnp.ndarray, backend: str = "direct",
     from repro.tuner.plan import Plan
     plan = Plan.default(spec, backend, L, temporal_steps=temporal_steps)
     return default_cache().engine(spec, plan, coefficients=coefficients)(x)
-
-
-# ---------------------------------------------------------------------------
-# Standalone 1-D utility (kept for callers outside the plan pipeline)
-# ---------------------------------------------------------------------------
-
-def apply_1d(w: np.ndarray, x: jnp.ndarray, n_out: int, axis: int,
-             backend: str, L: Optional[int] = None) -> jnp.ndarray:
-    """Apply a 1-D stencil kernel along ``axis`` of ``x`` (halo included)."""
-    r = (w.shape[0] - 1) // 2
-    if L is None:
-        L = default_l(r)
-    x = jnp.moveaxis(x, axis, 0)
-    rest = x.shape[1:]
-    x2d = x.reshape(x.shape[0], -1)
-    if backend == "direct":
-        y = _op_direct(np.asarray(w), x2d, n_out)
-    elif backend == "gemm":
-        y = _op_gemm(kernel_matrix(w, L=L, pad_width=True), x2d, n_out, L)
-    elif backend == "sptc":
-        sk = sparsify_stencil_kernel(w, L=L)
-        comb = np.asarray(sk.perm)[sk.sparse.gather_indices()]
-        y = _op_sptc(sk.values, comb, x2d, n_out, L)
-    elif backend == "pallas_mxu":
-        y = _op_pallas_mxu(kernel_matrix(w, L=L, pad_width=True), x2d,
-                           n_out, L)
-    elif backend == "pallas_sptc":
-        sk = sparsify_stencil_kernel(w, L=L)
-        y = _op_pallas_sptc(sk.sparse, sk.perm, x2d, n_out, L,
-                            star_fast=True)
-    else:
-        raise ValueError(f"unknown 1-D backend {backend}")
-    return jnp.moveaxis(y.reshape((n_out,) + rest), 0, axis)

@@ -9,7 +9,7 @@ in its ``op_name`` (``bench/scopes.py`` reads them).
 
 The Pallas kernels carry names of their own (``pallas_call(name=...)``):
 ``sptc_onehot`` and ``sptc_star`` (the fused SpTC kernel's two paths),
-``sptc_spmm``, ``mxu_windows``, ``stencil_direct`` and ``conv1d``.
+``sptc_onehot3d``, ``mxu_windows``, ``stencil_direct`` and ``conv1d``.
 """
 from __future__ import annotations
 

@@ -201,8 +201,7 @@ def _row_coefficient_slabs(
 # --------------------------------------------------------------------------
 
 def lower_spec(spec: StencilSpec, backend: str = "direct",
-               L: Optional[int] = None, star_fast_path: bool = True,
-               fuse_rows: bool = False, temporal_steps: int = 1,
+               L: Optional[int] = None, fuse_rows: bool = False, temporal_steps: int = 1,
                coefficients: Optional[np.ndarray] = None) -> "LoweredPlan":
     """Lower a stencil spec into an explicit :class:`LoweredPlan`.
 
@@ -259,7 +258,7 @@ def lower_spec(spec: StencilSpec, backend: str = "direct",
         if var:
             assert slabs is not None and coeff is not None
             slabs.append(coeff)
-    elif star_fast_path and spec.shape == "star":
+    elif spec.shape == "star":
         mode = "star-axis"
         axis_kernels = axis_decompose_star(spec)
         ops = [ir.RowOp(axis=a, lead=(), operand=a) for a in range(d)]

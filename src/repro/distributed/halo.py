@@ -101,7 +101,7 @@ class ShardedStencilEngine:
     counts, which is passed to :func:`grid_mesh`.  Mesh axes of extent 1
     are degenerate (no exchange, plain zero padding) and are dropped.
 
-    All plan knobs (``backend``, ``L``, ``fuse_rows``, ``star_fast_path``,
+    All plan knobs (``backend``, ``L``, ``fuse_rows``,
     ``temporal_steps``) mean exactly what they mean on ``StencilEngine``;
     the lowering is shared and untouched.  Variable coefficients are not
     supported (the per-field tables are fixed to the global shape and do
@@ -110,8 +110,7 @@ class ShardedStencilEngine:
 
     def __init__(self, spec: StencilSpec, mesh, *,
                  backend: str = "direct", L: Optional[int] = None,
-                 star_fast_path: bool = True, fuse_rows: bool = False,
-                 temporal_steps: int = 1,
+                 fuse_rows: bool = False, temporal_steps: int = 1,
                  grid_axes: Optional[Sequence[int]] = None) -> None:
         if isinstance(mesh, (int, tuple, list)):
             mesh = grid_mesh(mesh)
@@ -144,8 +143,8 @@ class ShardedStencilEngine:
             for a, name in zip(axes, mesh.axis_names)
             if int(mesh.shape[name]) > 1}
         self.plan_ir: LoweredPlan = lower_spec(
-            spec, backend=backend, L=L, star_fast_path=star_fast_path,
-            fuse_rows=fuse_rows, temporal_steps=temporal_steps)
+            spec, backend=backend, L=L, fuse_rows=fuse_rows,
+            temporal_steps=temporal_steps)
         self.L = self.plan_ir.L
         self._step_fn = emit(self.plan_ir)
         entries: list = [None] * spec.ndim

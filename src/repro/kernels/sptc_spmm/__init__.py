@@ -1,5 +1,3 @@
-from repro.kernels.sptc_spmm.ops import sptc_spmm, sptc_spmm_windows
-from repro.kernels.sptc_spmm.ref import sptc_spmm_ref, sptc_spmm_windows_ref
+from repro.kernels.sptc_spmm.ops import sptc_planes, sptc_spmm_fused
 
-__all__ = ["sptc_spmm", "sptc_spmm_windows", "sptc_spmm_ref",
-           "sptc_spmm_windows_ref"]
+__all__ = ["sptc_planes", "sptc_spmm_fused"]

@@ -1,16 +1,12 @@
-"""Pallas TPU kernels: 2:4-compressed SpMM (simulated Sparse Tensor Core).
+"""Pallas TPU kernels: the fused 2:4 SpTC stencil executors.
 
-Two entry points:
+TPU has no Sparse Tensor Core, so the kernels realise ``mma.sp``'s
+selection (per output row and 4-wide reduction segment, only the two
+input rows the 2-bit metadata names contribute) as an in-VMEM
+decompression followed by a dense MXU matmul over the free dimension.
 
-``sptc_spmm_call`` — the v1 building block: a plain compressed SpMM over a
-pre-swapped RHS.  Faithful executable semantics of ``mma.sp``: per output
-row i and 4-wide reduction segment s, only the two RHS rows selected by
-the 2-bit metadata contribute.  TPU has no SpTC, so the kernel realizes
-the selection as an in-VMEM decompression (VPU one-hot expansion over the
-tiny K dim) followed by a dense MXU matmul over the N (free) dimension.
-
-``sptc_fused_call`` — the v2 fused stencil executor (paper §3.3 "zero
-runtime overhead"): ONE Pallas program that, per (N-tile, row-tile) grid
+``sptc_fused_call`` — one 1-D application (paper §3.3 "zero runtime
+overhead"): ONE Pallas program that, per (N-tile, row-tile) grid
 step,
 
   1. DMAs the overlapping input window of its ``B`` output rows straight
@@ -25,11 +21,13 @@ step,
   3. runs the dense MXU matmul (f32, or bf16 inputs with f32
      accumulation via ``compute_dtype="bfloat16"``).
 
-Star fast path (``star_fast=True``): when the composed swap∘meta gather
-is the identity band of the taps (see ``core.sparsify
-.contiguous_band_values``), the metadata carries no information — the
-kernel skips the one-hot decompression and performs K/2 shifted VPU FMAs
-over the banded value layout, touching no metadata at all.
+``star_fast`` names the program: ``False`` is the one-hot kernel above
+(``sptc_onehot``); ``True`` is the star kernel (``sptc_star``), for an
+operand whose composed swap∘meta gather is the identity band of the taps
+(``core.sparsify.contiguous_band_values``, which every star row op is):
+the metadata carries no information, so it skips the decompression and
+performs K/2 shifted VPU FMAs over the banded value layout.  The engine
+picks it from the plan's decomposition mode.
 
 Blocking: the compressed operand (M = L, K/2) and metadata words are tiny
 and live whole in VMEM; the input stays in HBM (``pl.ANY``) because the
@@ -55,6 +53,9 @@ rows its outputs read, so the wrapper pads no more rows than steps of
 ``(n_out, C)``, the last blocks' writes clipped, so nothing is cropped.
 A leading batch grid axis serves ``vmap`` (``common.fold_vmap``).
 
+``sptc_planes_call`` — every row op of a radius-1 3-D box step in one
+program (``sptc_onehot3d``) over the state's own layout; see its section.
+
 Both ``*_call`` entry points resolve ``interpret=None`` through
 ``common.default_interpret()`` at call time: compiled Mosaic on a real
 TPU, interpret mode elsewhere, overridable via ``REPRO_PALLAS_INTERPRET``.
@@ -74,66 +75,8 @@ from repro.kernels import common
 from repro.kernels.common import round_up
 
 
-def _sptc_kernel(values_ref, meta_ref, x_ref, y_ref, *, k: int):
-    vals = values_ref[:]                       # (M, K/2)
-    meta = meta_ref[:]                         # (M, K/2) int32
-    x = x_ref[:]                               # (K, bn)
-    m, kh = vals.shape
-    # gather index per compressed slot: 4*segment + 2-bit position
-    seg = (jax.lax.broadcasted_iota(jnp.int32, (m, kh), 1) // 2) * 4
-    gidx = seg + meta                          # (M, K/2)
-    # In-VMEM decompression: scatter values to their K positions via one-hot.
-    # K is tiny (= 2L); this is VPU work, the MXU then runs the dense dot.
-    kpos = jax.lax.broadcasted_iota(jnp.int32, (m, kh, k), 2)
-    onehot = (gidx[:, :, None] == kpos).astype(vals.dtype)
-    w = jnp.sum(vals[:, :, None] * onehot, axis=1)          # (M, K)
-    y_ref[:] = jnp.dot(w, x, precision=common.dot_precision(x.dtype),
-                       preferred_element_type=jnp.float32
-                       ).astype(y_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def _sptc_spmm_jit(values, meta, x, *, block_n: int, interpret: bool):
-    m, kh = values.shape
-    k, n = x.shape
-    if kh * 2 != k:
-        raise ValueError(f"K/2 mismatch: values {kh} vs x K={k}")
-    bn = min(block_n, round_up(n, 128))
-    n_pad = round_up(n, bn)
-    with jax.named_scope(KERNEL_PREP):
-        if n_pad != n:
-            x = jnp.pad(x, ((0, 0), (0, n_pad - n)))
-        values = values.astype(x.dtype)
-        meta = meta.astype(jnp.int32)
-    grid = (n_pad // bn,)
-    y = pl.pallas_call(
-        functools.partial(_sptc_kernel, k=k),
-        name="sptc_spmm",
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((m, kh), lambda i: (0, 0)),     # compressed values
-            pl.BlockSpec((m, kh), lambda i: (0, 0)),     # metadata
-            pl.BlockSpec((k, bn), lambda i: (0, i)),     # RHS N-tile
-        ],
-        out_specs=pl.BlockSpec((m, bn), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((m, n_pad), x.dtype),
-        interpret=interpret,
-    )(values, meta, x)
-    with jax.named_scope(KERNEL_PREP):
-        return y[:, :n]
-
-
-def sptc_spmm_call(values, meta, x, *, block_n: int = 512,
-                   interpret: bool | None = None):
-    """y = SpTC(values, meta) @ x.   values/meta: (M, K/2); x: (K, N)."""
-    if interpret is None:
-        interpret = common.default_interpret()
-    return _sptc_spmm_jit(values, meta, x, block_n=block_n,
-                          interpret=interpret)
-
-
 # ---------------------------------------------------------------------------
-# v2: fused window-DMA + in-kernel swap/gather + MXU matmul
+# one 1-D application: window DMA + in-kernel swap/gather + MXU matmul
 # ---------------------------------------------------------------------------
 
 def _fused_kernel(x_hbm, vals_ref, meta_ref, y_ref, scratch, sem,
@@ -357,7 +300,7 @@ def _sptc_fused_jit(x3d, values, meta_bits, *, n_out: int, L: int,
 
 
 def sptc_fused_call(values, meta_bits, x2d, *, n_out: int, L: int,
-                    star_fast: bool = False,
+                    star_fast: bool,
                     compute_dtype: str | None = None,
                     interpret: bool | None = None):
     """Fused stencil SpMM: y[i] = sum_j band(i, j) * x2d[i + ...].

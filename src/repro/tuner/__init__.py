@@ -10,7 +10,7 @@ depends on stencil shape/radius, problem size, dtype and device kind
 is measured once, cached, and persisted — never guessed per call.
 
 Layers:
-  plan.py    Plan (backend, L, fuse_rows, star_fast_path) and the cache
+  plan.py    Plan (backend, L, fuse_rows, temporal_steps) and the cache
              key: (spec fingerprint, shape bucket, dtype, device kind).
   search.py  candidate enumeration + warmup/median timing autotuner with
              a static cost-model fallback (reuses core/analysis.py ideas).

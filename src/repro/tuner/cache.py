@@ -129,7 +129,6 @@ class PlanCache:
         if eng is None:
             self.stats.engine_builds += 1
             eng = StencilEngine(spec, backend=plan.backend, L=plan.L,
-                                star_fast_path=plan.star_fast_path,
                                 fuse_rows=plan.fuse_rows,
                                 temporal_steps=plan.temporal_steps,
                                 coefficients=coefficients)
@@ -198,7 +197,6 @@ class PlanCache:
             self.stats.engine_builds += 1
             eng = ShardedStencilEngine(
                 spec, mesh, backend=plan.backend, L=plan.L,
-                star_fast_path=plan.star_fast_path,
                 fuse_rows=plan.fuse_rows,
                 temporal_steps=plan.temporal_steps,
                 grid_axes=grid_axes)

@@ -31,7 +31,8 @@ from repro.core.stencil import StencilSpec
 from repro.core.transform import default_l
 
 #: serialization schema for Plan dicts and PlanKey strings.
-#:   1  (implicit) backend/L/fuse_rows/star_fast_path; unversioned keys
+#:   1  (implicit) backend/L/fuse_rows and a star-path flag (since
+#:      dropped; decodes as any unknown field); unversioned keys
 #:   2  + temporal_steps on Plan; versioned keys + coeff/steps fields
 #:   3  + univ (backend-universe provenance) on PlanKey — plans tuned
 #:      with the Pallas backends forced in (REPRO_TUNER_INCLUDE_PALLAS
@@ -52,14 +53,12 @@ class Plan:
     backend: str
     L: int
     fuse_rows: bool = False
-    star_fast_path: bool = True
     temporal_steps: int = 1
 
     def to_dict(self) -> dict:
         return {"schema": PLAN_SCHEMA,
                 "backend": self.backend, "L": int(self.L),
                 "fuse_rows": bool(self.fuse_rows),
-                "star_fast_path": bool(self.star_fast_path),
                 "temporal_steps": int(self.temporal_steps)}
 
     @classmethod
@@ -76,7 +75,6 @@ class Plan:
                 f"{PLAN_SCHEMA}")
         return cls(backend=str(d["backend"]), L=int(d["L"]),
                    fuse_rows=bool(d.get("fuse_rows", False)),
-                   star_fast_path=bool(d.get("star_fast_path", True)),
                    temporal_steps=int(d.get("temporal_steps", 1)))
 
     @classmethod

@@ -81,7 +81,7 @@ def candidate_plans(spec: StencilSpec, device: str | None = None, *,
 def _n_applications(spec: StencilSpec, plan: Plan) -> int:
     if spec.ndim == 1:
         return 1
-    if plan.star_fast_path and spec.shape == "star":
+    if spec.shape == "star":
         return spec.ndim
     return len(decompose_rows(spec))
 
@@ -137,7 +137,6 @@ def _default_engine_factory(spec: StencilSpec, plan: Plan,
                             coefficients: Any = None) -> "StencilEngine":
     from repro.core.engine import StencilEngine
     return StencilEngine(spec, backend=plan.backend, L=plan.L,
-                         star_fast_path=plan.star_fast_path,
                          fuse_rows=plan.fuse_rows,
                          temporal_steps=plan.temporal_steps,
                          coefficients=coefficients)

@@ -20,41 +20,7 @@ def _tol(dtype):
 
 
 # ---------------------------------------------------------------------------
-# sptc_spmm — the faithful simulated-SpTC kernel
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("r,n", [(1, 64), (1, 200), (2, 128), (3, 384),
-                                 (5, 96), (7, 513)])
-def test_sptc_spmm_vs_ref(r, n, dtype, rng):
-    from repro.kernels.sptc_spmm.ops import sptc_spmm
-    from repro.kernels.sptc_spmm.ref import sptc_spmm_ref
-    sk = sparsify_stencil_kernel(rng.normal(size=2 * r + 1))
-    x = jnp.asarray(rng.normal(size=(2 * sk.L, n)), dtype)
-    vals = jnp.asarray(sk.values, dtype)
-    meta = jnp.asarray(sk.meta)
-    got = sptc_spmm(vals, meta, x, interpret=True)
-    want = sptc_spmm_ref(vals, meta, x)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), **_tol(dtype))
-
-
-@pytest.mark.parametrize("t", [1, 3, 8])
-def test_sptc_spmm_windows_vs_ref(t, rng):
-    from repro.kernels.sptc_spmm.ops import sptc_spmm_windows
-    from repro.kernels.sptc_spmm.ref import sptc_spmm_windows_ref
-    sk = sparsify_stencil_kernel(rng.normal(size=5))        # r = 2
-    win = jnp.asarray(rng.normal(size=(t, 2 * sk.L, 130)), jnp.float32)
-    vals = jnp.asarray(sk.values, jnp.float32)
-    meta = jnp.asarray(sk.meta)
-    got = sptc_spmm_windows(vals, meta, win, interpret=True)
-    want = sptc_spmm_windows_ref(vals, meta, win)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-
-# ---------------------------------------------------------------------------
-# sptc_spmm fused v2 — window DMA + in-kernel swap/gather + MXU, one program
+# sptc_spmm fused — window DMA + in-kernel swap/gather + MXU, one program
 # ---------------------------------------------------------------------------
 
 def _direct_1d(w, x, n_out):
@@ -62,17 +28,57 @@ def _direct_1d(w, x, n_out):
                      for i in range(n_out)])
 
 
-@pytest.mark.parametrize("r,c", [(1, 64), (1, 200), (2, 128), (3, 384)])
-def test_sptc_fused_general_vs_direct(r, c, rng):
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("r,c", [(1, 64), (1, 200), (2, 128), (3, 384),
+                                 (5, 96), (7, 513)])
+def test_sptc_fused_general_vs_direct(r, c, dtype, rng):
     from repro.kernels.sptc_spmm.ops import sptc_spmm_fused
     w = rng.normal(size=2 * r + 1)
     sk = sparsify_stencil_kernel(w)
     n_out = 3 * sk.L + 2
-    x = rng.normal(size=(n_out + 2 * r, c)).astype(np.float32)
-    got = sptc_spmm_fused(sk.sparse, sk.perm, jnp.asarray(x), n_out=n_out,
+    x = jnp.asarray(rng.normal(size=(n_out + 2 * r, c)), dtype)
+    got = sptc_spmm_fused(sk.sparse, sk.perm, x, n_out=n_out,
                           L=sk.L, star_fast=False, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), _direct_1d(w, x, n_out),
-                               rtol=2e-5, atol=2e-5)
+    assert got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               _direct_1d(w, np.asarray(x, np.float32), n_out),
+                               **_tol(dtype))
+
+
+@pytest.mark.parametrize("t", [1, 3, 8])
+def test_sptc_fused_vmap_vs_direct(t, rng):
+    """``vmap`` over a batch of inputs runs the one-hot kernel on its own
+    batch grid axis (``common.fold_vmap``)."""
+    import jax
+    from repro.kernels.sptc_spmm.ops import sptc_spmm_fused
+    w = rng.normal(size=5)                                   # r = 2
+    sk = sparsify_stencil_kernel(w)
+    n_out = 2 * sk.L + 3
+    x = rng.normal(size=(t, n_out + 4, 130)).astype(np.float32)
+    call = lambda v: sptc_spmm_fused(sk.sparse, sk.perm, v, n_out=n_out,
+                                     L=sk.L, star_fast=False, interpret=True)
+    got = jax.vmap(call)(jnp.asarray(x))
+    want = np.stack([_direct_1d(w, xb, n_out) for xb in x])
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("d", [1, 2, 3])
+def test_pallas_sptc_star_ops_are_bands(d):
+    """Every row op ``lower_spec`` makes for a star on ``pallas_sptc``, at
+    every radius 1-7 and every tuner ``L``, is a contiguous band of its
+    taps, so the engine can always run a star on the star kernel."""
+    from repro.core.sparsify import contiguous_band_values
+    from repro.core.transform import lower_spec
+    from repro.tuner.search import l_candidates
+    for r in range(1, 8):
+        spec = make_stencil("star", d, r, seed=r)
+        for L in l_candidates(r):
+            plan = lower_spec(spec, backend="pallas_sptc", L=L)
+            sp = plan.sparsify
+            assert plan.decompose.mode in ("single", "star-axis")
+            for op in plan.decompose.ops:
+                assert contiguous_band_values(
+                    sp.operands[op.operand], sp.perm) is not None, (r, L, op)
 
 
 @pytest.mark.parametrize("r", [1, 2, 3])
@@ -99,10 +105,25 @@ def test_sptc_fused_bf16_accumulates_f32(rng):
     n_out = 2 * sk.L
     x = rng.normal(size=(n_out + 4, 128)).astype(np.float32)
     got = sptc_spmm_fused(sk.sparse, sk.perm, jnp.asarray(x), n_out=n_out,
-                          L=sk.L, compute_dtype="bfloat16", interpret=True)
+                          L=sk.L, star_fast=True, compute_dtype="bfloat16",
+                          interpret=True)
     assert got.dtype == jnp.float32          # output stays in input dtype
     np.testing.assert_allclose(np.asarray(got), _direct_1d(w, x, n_out),
                                rtol=3e-2, atol=3e-2)
+
+
+def test_sptc_fused_star_rejects_non_band_operand(rng):
+    """The star kernel takes only an operand whose swap∘meta gather is the
+    identity band of the taps: another one raises, with no fallback."""
+    from repro.core.sparsify import encode_24, strided_swap_perm
+    from repro.kernels.sptc_spmm.ops import sptc_spmm_fused
+    L = 4
+    mat = np.zeros((L, 2 * L))
+    mat[0, [4, 6]] = 1.0          # row 0 reads input rows 4 and 6: off-band
+    x = jnp.asarray(rng.normal(size=(L + 2, 64)), jnp.float32)
+    with pytest.raises(ValueError, match="identity band"):
+        sptc_spmm_fused(encode_24(mat), strided_swap_perm(L), x, n_out=L,
+                        L=L, star_fast=True)
 
 
 def test_sptc_fused_rejects_non_swap_perm(rng):
@@ -110,7 +131,8 @@ def test_sptc_fused_rejects_non_swap_perm(rng):
     sk = sparsify_stencil_kernel(rng.normal(size=3))
     x = jnp.asarray(rng.normal(size=(20, 64)), jnp.float32)
     with pytest.raises(ValueError, match="strided-swap"):
-        sptc_spmm_fused(sk.sparse, np.arange(2 * sk.L), x, n_out=8, L=sk.L)
+        sptc_spmm_fused(sk.sparse, np.arange(2 * sk.L), x, n_out=8, L=sk.L,
+                        star_fast=True)
 
 
 def _pallas_eqns(jaxpr):
@@ -263,23 +285,26 @@ def test_all_call_entry_points_default_interpret_to_backend():
     import inspect
     from repro.kernels.conv1d.kernel import conv1d_causal_call
     from repro.kernels.sptc_spmm.kernel import (sptc_fused_call,
-                                                sptc_spmm_call)
+                                                sptc_planes_call)
     from repro.kernels.stencil_direct.kernel import stencil2d_call
     from repro.kernels.stencil_gemm.kernel import windows_gemm_call
-    for fn in (sptc_spmm_call, sptc_fused_call, windows_gemm_call,
+    for fn in (sptc_fused_call, sptc_planes_call, windows_gemm_call,
                stencil2d_call, conv1d_causal_call):
         sig = inspect.signature(fn)
         assert sig.parameters["interpret"].default is None, fn.__name__
 
 
-def test_sptc_spmm_call_interpret_none_matches_explicit(rng):
-    from repro.kernels.sptc_spmm.kernel import sptc_spmm_call
+def test_sptc_fused_call_interpret_none_matches_explicit(rng):
+    from repro.kernels.sptc_spmm.kernel import sptc_fused_call
     sk = sparsify_stencil_kernel(rng.normal(size=3))
-    x = jnp.asarray(rng.normal(size=(2 * sk.L, 64)), jnp.float32)
+    n_out = 2 * sk.L
+    x = jnp.asarray(rng.normal(size=(n_out + 2, 64)), jnp.float32)
     vals = jnp.asarray(sk.values, jnp.float32)
-    meta = jnp.asarray(sk.meta)
-    got = sptc_spmm_call(vals, meta, x)                # None -> CPU -> True
-    want = sptc_spmm_call(vals, meta, x, interpret=True)
+    meta_bits = jnp.asarray(sk.sparse.meta_bits())
+    call = lambda **kw: sptc_fused_call(vals, meta_bits, x, n_out=n_out,
+                                        L=sk.L, star_fast=False, **kw)
+    got = call()                                       # None -> CPU -> True
+    want = call(interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 

@@ -31,7 +31,7 @@ def _x(spec, dims, rng, dtype=jnp.float32):
 # ---------------------------------------------------------------------------
 
 def test_plan_dict_roundtrip():
-    p = Plan(backend="sptc", L=8, fuse_rows=True, star_fast_path=False)
+    p = Plan(backend="sptc", L=8, fuse_rows=True, temporal_steps=2)
     assert Plan.from_dict(p.to_dict()) == p
 
 
@@ -171,8 +171,7 @@ def test_plan_from_dict_tolerates_unknown_and_missing_fields():
     assert Plan.from_dict(d) == Plan(backend="gemm", L=4)
     legacy = {"backend": "sptc", "L": 8}         # schema-1: fields default
     p = Plan.from_dict(legacy)
-    assert p == Plan(backend="sptc", L=8, fuse_rows=False,
-                     star_fast_path=True, temporal_steps=1)
+    assert p == Plan(backend="sptc", L=8, fuse_rows=False, temporal_steps=1)
     with pytest.raises(ValueError, match="schema"):
         Plan.from_dict({"schema": PLAN_SCHEMA + 1, "backend": "gemm", "L": 4})
 
